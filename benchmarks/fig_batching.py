@@ -17,7 +17,7 @@ work is visible next to the latency it bought.
 All dispatch modes share one engine: the shape-bucket executables are
 compiled once through the ordinary compile cache (width-1 buckets reuse
 the measure stage's executable outright) and reused across every mode;
-with ``--cache-dir`` the two-tier artifact cache makes warm reruns
+with ``--cache-dir`` the executable cache makes warm reruns
 zero-XLA-compile across *all* buckets and widths.
 
 As a section (``benchmarks/run.py --sections fig_batching``) it emits the
@@ -145,7 +145,7 @@ def main() -> int:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write the comparison as JSON (BENCH artifact)")
     ap.add_argument("--cache-dir", type=str, default=None,
-                    help="two-tier artifact cache: a warm dir restores every "
+                    help="executable cache: a warm dir restores every "
                          "bucket/width executable with zero XLA compiles")
     args = ap.parse_args()
 

@@ -18,7 +18,7 @@ Both clients serve the *same cached executable*: one compile per
 workload feeds the entire sweep (the engine's compile cache is keyed on
 the workload, not the serving client), and the script prints the cache
 traffic so "no recompile" is visible, not assumed. With ``--cache-dir``
-the sweep runs against the two-tier artifact cache: a warm directory
+the sweep runs against the executable cache: a warm directory
 restores serialized executables, so the whole figure — timer, roofline
 characterization, and every serving row — costs *zero* XLA compilations
 (the disk-cache summary printed at the end is the evidence).
@@ -181,7 +181,7 @@ def main() -> int:
                     help="host issue architectures to sweep side by side")
     ap.add_argument("--duration", type=float, default=0.3)
     ap.add_argument("--cache-dir", type=str, default=None,
-                    help="two-tier artifact cache directory: a warm dir "
+                    help="executable cache directory: a warm dir "
                          "restores serialized executables, making the "
                          "whole figure a zero-XLA-compile run")
     args = ap.parse_args()

@@ -109,7 +109,7 @@ def main() -> int:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write the scaling curve as JSON (BENCH artifact)")
     ap.add_argument("--cache-dir", type=str, default=None,
-                    help="shared two-tier artifact cache: client processes "
+                    help="shared executable cache: client processes "
                          "restore the executable instead of recompiling "
                          "(a warm dir makes every client zero-XLA-compile)")
     args = ap.parse_args()

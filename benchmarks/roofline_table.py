@@ -5,7 +5,7 @@ style of rows from engine records.
 
 The suite-report mode consumes what the engine's characterize stage
 attached to each record — which, on a warm ``--cache-dir`` run, was
-restored from the two-tier artifact cache without a single XLA
+restored from the executable cache without a single XLA
 compilation: one cold compile feeds the timer, this table, and the serve
 stage; warm runs feed all three with zero. The measured column prefers
 ``us_per_call_windowed`` (K calls in flight per synchronization) over the

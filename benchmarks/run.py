@@ -68,6 +68,9 @@ def main(argv=None) -> int:
         return 2
 
     # Imported after validation so a bad --sections fails fast, before jax.
+    from repro.core.engine import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         feat_coop_groups,
         feat_dynamic_parallelism,

@@ -26,6 +26,7 @@ def conv2d_xla(x, w):
     return jax.lax.conv_general_dilated(
         x, w, window_strides=(1, 1), padding="VALID",
         dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

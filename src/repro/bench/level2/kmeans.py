@@ -8,6 +8,8 @@ Validation: inertia is non-increasing across iterations.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -17,13 +19,17 @@ from repro.core.registry import BenchmarkSpec, Workload, register
 
 def kmeans_step(points: jax.Array, centers: jax.Array):
     """One Lloyd iteration. points (N, D), centers (K, D) -> (centers', inertia)."""
+    # Full f32 precision: the TPU's default rounds matmul operands to
+    # bf16, which moves assignments and centres by far more than f32
+    # rounding and makes placements disagree.
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
     x2 = jnp.sum(points * points, axis=1, keepdims=True)  # (N, 1)
     c2 = jnp.sum(centers * centers, axis=1)[None]  # (1, K)
-    d2 = x2 - 2.0 * points @ centers.T + c2  # (N, K)
+    d2 = x2 - 2.0 * dot(points, centers.T) + c2  # (N, K)
     assign = jnp.argmin(d2, axis=1)
     inertia = jnp.sum(jnp.min(d2, axis=1))
     onehot = jax.nn.one_hot(assign, centers.shape[0], dtype=points.dtype)  # (N, K)
-    sums = onehot.T @ points  # (K, D)
+    sums = dot(onehot.T, points)  # (K, D)
     counts = jnp.sum(onehot, axis=0)[:, None]
     new_centers = jnp.where(counts > 0, sums / jnp.maximum(counts, 1.0), centers)
     return new_centers, inertia

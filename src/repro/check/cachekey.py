@@ -35,7 +35,7 @@ _PLAN_FILE = "src/repro/core/plan.py"
 _HLOCACHE_FILE = "src/repro/core/hlocache.py"
 
 _KEY_BUILDERS = ("_cache_key", "_bucket_key")
-_DISK_CACHE_METHODS = {"load", "store", "note_skip", "load_tuned", "store_tuned"}
+_DISK_CACHE_METHODS = {"load", "store", "load_tuned", "store_tuned"}
 
 
 def _finding(file: str, line: int, message: str) -> Finding:

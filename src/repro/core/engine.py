@@ -110,7 +110,9 @@ from repro.core.results import (
 )
 from repro.obs import NULL_TRACER, NullTracer, Tracer, use_tracer
 
-__all__ = ["CompileCache", "Engine", "RunResult", "SweepStat"]
+__all__ = [
+    "CompileCache", "Engine", "RunResult", "SweepStat", "enable_compile_cache",
+]
 
 # (name, preset, frozen-overrides, backward, backend, devices, placement,
 #  impl, frozen-tuned-params). Mixed-shape serving appends ("vmap", width)
@@ -195,11 +197,12 @@ class Engine:
         tracer: Tracer | NullTracer | None = None,
     ) -> None:
         self.cache = cache if cache is not None else CompileCache()
-        # Optional cross-process persistence of compile artifacts (two
-        # tiers: serialized executables over lowered HLO text) — warm
-        # entries skip retracing, and usually XLA compilation too. None =
+        # Optional cross-process persistence of serialized executables —
+        # warm entries skip retracing and XLA compilation. None =
         # in-process only. The raw root is kept so distributed client
-        # processes can be pointed at the same cache.
+        # processes can be pointed at the same cache. JAX's own
+        # persistent compilation cache is placed by the entry points
+        # (enable_compile_cache), never here.
         self.cache_dir = cache_dir
         self.disk_cache = HloDiskCache(cache_dir) if cache_dir else None
         # Structured tracing (repro.obs): every _stage_* becomes a span,
@@ -208,8 +211,6 @@ class Engine:
         # Default NULL_TRACER: falsy, no-op spans, swallowed counters —
         # the disabled cost at a guarded call site is one attribute read.
         self.tracer = tracer if tracer is not None else NULL_TRACER
-        if cache_dir:
-            _enable_jax_persistent_cache(cache_dir)
 
     # -- stages ------------------------------------------------------------
 
@@ -353,20 +354,14 @@ class Engine:
                     executable=fn,
                     info=empty_compiled_info(_pass_name(workload, backward)),
                 )
-            # Disk cache: a warm entry skips the retrace — and, when the
-            # serialized executable deserializes, the XLA compile too; a
-            # cold or failed one falls through. Multi-device lowerings
-            # embed placement-dependent shardings and device assignments,
-            # so they persist through the sharded tier (AOT-serialized
-            # jax.stages.Compiled under an explicit topology key) instead
-            # of the raw single-device executable tier.
+            # Disk cache: a warm entry skips the retrace and the XLA
+            # compile; a cold or failed one falls through.
             return self._compile_through_caches(
                 key, workload, fn, args,
                 pass_name=_pass_name(workload, backward),
                 impl=impl,
                 tuned_params=tuned_params,
                 use_disk=self.disk_cache is not None,
-                sharded=placement.devices > 1,
             )
 
         return self.cache.lookup(key, build)
@@ -382,19 +377,14 @@ class Engine:
         impl: str,
         tuned_params: dict | None,
         use_disk: bool,
-        sharded: bool = False,
     ) -> _CacheEntry:
         """Lower + compile one program through the disk cache: a warm
-        entry skips the retrace — and, when the serialized executable
-        deserializes, the XLA compile too. Shared by the measure-path
-        compile stage and the mixed-shape serve stage's per-(bucket,
-        width) executables, so every bucket persists and restores exactly
-        like a measure executable. ``sharded`` routes multi-device
-        programs through the cache's sharded tier (the lowering embeds
-        device assignments, so it persists as an AOT-serialized
-        ``jax.stages.Compiled`` rather than a raw executable blob)."""
+        entry skips the retrace and the XLA compile. Shared by the
+        measure-path compile stage and the mixed-shape serve stage's
+        per-(bucket, width) executables, so every bucket persists and
+        restores exactly like a measure executable."""
         if use_disk:
-            loaded = self.disk_cache.load(key, args, sharded=sharded)
+            loaded = self.disk_cache.load(key, args)
             if loaded is not None:
                 executable, info = loaded
                 return _CacheEntry(executable=executable, info=info)
@@ -406,7 +396,7 @@ class Engine:
             lowered = jax.jit(fn).lower(*args)
         compiled = lowered.compile()
         if use_disk:
-            self.disk_cache.store(key, lowered, compiled, pass_name, sharded=sharded)
+            self.disk_cache.store(key, compiled, pass_name)
         return _CacheEntry(executable=compiled)
 
     def _stage_tune(
@@ -705,7 +695,7 @@ class Engine:
         """Precompile one executable per (shape bucket, batch width).
 
         Every program goes through the in-process CompileCache AND the
-        two-tier disk cache under a bucket-specific key, so a warm run
+        disk executable cache under a bucket-specific key, so a warm run
         restores the whole table with zero XLA compiles. Batch member j
         gets inputs from ``make_inputs(seed + j)`` — a width-w program
         computes w *distinct* requests, stacked on a new leading axis and
@@ -1029,6 +1019,27 @@ class Engine:
         with self._timed_stage("characterize", timings, bench=spec.name):
             return self._stage_characterize(workload, entry, backward)
 
+    def outputs(self, spec: BenchmarkSpec, plan: ExecutionPlan, devices: int):
+        """One forward execution of ``spec`` at the plan's preset, placed
+        on ``devices`` as the plan's placement says, through the compile
+        cache (a sweep that already ran this point compiles nothing).
+        Returns the outputs on the host — what a cross-placement agreement
+        check compares."""
+        preset = plan.resolve_preset(spec)
+        timings: dict[str, float] = {}
+        with self._timed_stage("build", timings, bench=spec.name):
+            workload, args = self._stage_build(spec, plan, preset)
+        with self._timed_stage("place", timings, bench=spec.name):
+            args, placement = self._stage_place(
+                workload, args, plan.placement_at(devices)
+            )
+        impl, _ = self._resolve_impl(workload, plan, False)
+        with self._timed_stage("compile", timings, bench=spec.name):
+            entry = self._stage_compile(
+                spec, workload, args, plan, preset, False, placement, impl
+            )
+        return jax.device_get(entry.executable(*args))
+
     # -- orchestration -----------------------------------------------------
 
     def run(
@@ -1052,6 +1063,10 @@ class Engine:
                 get_benchmark(plan.serve.colocate)
             except KeyError as e:
                 raise PlanError(str(e)) from None
+        if plan.serve is not None and plan.serve.client_procs > 0:
+            from repro.dist.launcher import refuse_children_on_device
+
+            refuse_children_on_device()
         if plan.serve is not None and plan.serve.is_mixed and want > 1:
             raise PlanError(
                 "mixed-shape serving (mix/trace/batcher dispatch) is "
@@ -1297,23 +1312,39 @@ class Engine:
             return [err]
 
 
-def _enable_jax_persistent_cache(cache_dir: str) -> None:
-    """Point jax's own persistent compilation cache at a subdirectory of
-    the engine's cache dir. The two-tier artifact cache covers the
-    benchmark executables; this covers everything *around* them — input
-    builders, validators, one-off jnp ops — which otherwise re-compile in
-    every process and dominate warm-run wall time. Best-effort and
-    process-global (last cache_dir wins): older jaxlibs without CPU
-    support simply skip it."""
-    try:
-        jax.config.update(
-            "jax_compilation_cache_dir",
-            os.path.join(cache_dir, "jax-persistent"),
+# Fixed default for JAX's persistent compilation cache: <checkout>/.jax_cache
+# (gitignored). The path is part of the cache's key, so it never moves.
+_CHECKOUT_JAX_CACHE = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    ),
+    ".jax_cache",
+)
+
+
+def enable_compile_cache(cache_dir: str | None = None) -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+
+    Called once by each entry point (the suite CLI, ``benchmarks/run.py``,
+    ``chip_smoke.py``) before anything compiles — never on import, never
+    by :class:`Engine`. The rule: ``JAX_COMPILATION_CACHE_DIR``, when set,
+    wins and JAX reads it itself (no other directory is set here); else
+    ``<cache_dir>/jax-persistent`` when a ``--cache-dir`` was given; else
+    the fixed ``<checkout>/.jax_cache``. The executable cache covers the
+    benchmark programs; this one covers everything around them — input
+    builders, validators, one-off jnp ops. Configuration errors raise.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = (
+            os.path.join(cache_dir, "jax-persistent")
+            if cache_dir
+            else _CHECKOUT_JAX_CACHE
         )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # noqa: BLE001 — an accelerator, never a failure
-        pass
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
 
 
 def _pass_name(workload: Workload, backward: bool) -> str:

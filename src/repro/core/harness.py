@@ -31,7 +31,7 @@ Layering (post staged-engine refactor): this module holds the *primitives*
 — ``time_fn`` for an already-compiled callable, ``characterize_compiled``
 for the static analysis of a compiled executable, and small constructors
 for the result dataclasses. The staged path that compiles each workload
-exactly once (or restores it from the two-tier disk cache without any
+exactly once (or restores it from the disk executable cache without any
 compilation) and feeds the same executable to the timer, the roofline
 characterization, and the serve stage lives in ``core/engine.py``;
 ``time_workload`` / ``compile_workload`` remain as standalone one-shot
@@ -106,17 +106,14 @@ def commit_args(args: Sequence[Any]) -> tuple:
     Leaves that are already ``jax.Array`` (including placed/sharded
     arrays) pass through untouched; numpy arrays and python scalars are
     ``device_put`` and blocked on, so a timing loop over the result never
-    pays per-call H2D transfer. Non-array leaves it cannot commit (e.g.
-    ``ShapeDtypeStruct`` in dry-run flows) also pass through unchanged.
+    pays per-call H2D transfer. Abstract leaves (``jax.ShapeDtypeStruct``
+    in dry-run flows) also pass through unchanged.
     """
 
     def commit(leaf: Any) -> Any:
-        if isinstance(leaf, jax.Array):
+        if isinstance(leaf, (jax.Array, jax.ShapeDtypeStruct)):
             return leaf
-        try:
-            return jax.block_until_ready(jax.device_put(leaf))
-        except (TypeError, ValueError):
-            return leaf
+        return jax.block_until_ready(jax.device_put(leaf))
 
     return tuple(jax.tree_util.tree_map(commit, tuple(args)))
 

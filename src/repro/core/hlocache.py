@@ -1,23 +1,18 @@
-"""Cross-process persistence of compile artifacts — a two-tier cache that
-closes the ROADMAP's "serialized executables" open item.
+"""Cross-process persistence of compiled executables.
 
 The in-process :class:`~repro.core.engine.CompileCache` dies with the
 process, so every CI suite run re-pays tracing *and* XLA compilation for
-every workload. This cache persists, per compile-cache key, **two tiers**
-of what the compile stage produced, plus the static characterization
-(cost / memory / collective bytes) that rebuilds
-:class:`~repro.core.harness.CompiledInfo` without touching an executable:
-
-- **Tier 1 — serialized executable** (``<key>.exe``): the AOT-serialized
-  compiled executable (``backend.serialize_executable``). A warm load
-  deserializes it straight into a runnable — *zero* retracing and *zero*
-  XLA compilation. This is what makes a warm ``--cache-dir`` suite run a
-  zero-compile run.
-- **Tier 2 — lowered HLO text** (``<key>.json``): the StableHLO module
-  text. A warm load hands it to the backend compiler (``client.compile``)
-  — it still pays one XLA compilation but skips Python retracing. This is
-  the fallback when the executable blob is missing or no longer
-  deserializes (toolchain drift).
+every workload. This cache persists, per compile-cache key, the
+AOT-serialized ``jax.stages.Compiled`` (``<key>.exe``, through the public
+``jax.experimental.serialize_executable`` API) plus a JSON payload
+(``<key>.json``) holding the static characterization (cost / memory /
+collective bytes) that rebuilds :class:`~repro.core.harness.CompiledInfo`
+without touching an executable, and the ids of the devices the program
+was compiled for. A warm load deserializes the executable straight into
+a runnable — *zero* retracing and *zero* XLA compilation — onto those
+same devices, so single-device and sharded (multi-device) programs share
+one path: shardings, argument pruning and the pytree call convention all
+round-trip.
 
 A third sidecar (``<key>.tune.json``, :meth:`store_tuned` /
 :meth:`load_tuned`) persists the engine's autotune winner — the Pallas
@@ -35,42 +30,15 @@ and a content hash of the ``repro`` package source (a new toolchain *or
 an edited kernel* gets a fresh directory rather than stale artifacts),
 keyed by a hash of the engine's compile-cache key.
 
-**Multi-device (sharded) entries** persist too: their lowerings embed
-placement-dependent shardings and device assignments, so the raw
-executable tier would silently collapse outputs to one shard. They go
-through a dedicated sharded tier instead — the whole
-``jax.stages.Compiled`` AOT-serialized via
-``jax.experimental.serialize_executable`` (payload + in/out trees), which
-round-trips sharding, argument pruning, and the pytree call convention.
-A sharded entry has **no HLO-text tier**: recompiling the stored text
-would target a single device, so an unusable sharded blob degrades
-straight to retracing. Each sharded payload records the topology it was
-compiled for and a load under a different topology is a counted
-fallback, never a wrong answer. (Pre-v3 behaviour — skipping the disk
-cache for multi-device placements, counted in ``skips`` — is retired;
-``note_skip`` remains for callers that decline lookups for other
-reasons.)
-
 Every warm load is validated by one trial execution; *any* failure —
-corrupt file, toolchain drift, call-convention mismatch — degrades one
-tier at a time: executable → HLO text → the normal trace-and-compile
-path. The cache can only ever make a run faster, never wronger.
-Degradations are *counted and explained* rather than swallowed:
-``exe_fallbacks`` / ``last_exe_fallback`` record executables that no
-longer deserialize (the run then pays one compile from tier 2), and
-``fallback_count`` / ``fallback_reasons`` / ``last_fallback`` record
-entries that fell all the way back to retracing. ``xla_compiles`` counts
-the compilations the cache itself triggered (tier-2 loads), so "the warm
-run performed zero XLA compiles" is an assertable counter:
-``exe_hits == lookups`` with ``hlo_hits == misses == fallbacks == 0``.
-``summary()`` is the one-line diagnosis the engine prints in verbose runs.
-
-Caveat: warm entries execute through the backend client's raw call
-convention rather than ``jax.jit``'s dispatch path, which adds a few
-hundred microseconds of host overhead per call. This cache is a CI /
-repeat-run accelerator (where wall-clock is dominated by tracing and
-compilation); runs whose *measured microseconds* are the artifact should
-stay cold — or read the windowed column, which amortizes dispatch.
+corrupt file, toolchain drift, a device the entry names that this host
+lacks — falls back to the normal trace-and-compile path. The cache can
+only ever make a run faster, never wronger. Fallbacks are *counted and
+explained* rather than swallowed: ``fallback_count`` /
+``fallback_reasons`` / ``last_fallback`` record present-but-unusable
+entries, so "the warm run restored everything" is an assertable counter:
+``hits == lookups`` with ``misses == fallback_count == 0``. ``summary()``
+is the one-line diagnosis the engine prints in verbose runs.
 """
 
 from __future__ import annotations
@@ -83,34 +51,20 @@ import re
 from typing import Any, Callable
 
 import jax
-import jax.numpy as jnp
 
-from repro.core.harness import CompiledInfo
-from repro.core.metrics import roofline_terms
+from repro.core.harness import CompiledInfo, _memory_analysis_dict
+from repro.core.metrics import (
+    collective_bytes_from_hlo,
+    cost_analysis_dict,
+    roofline_terms,
+)
 
 __all__ = ["HloDiskCache"]
 
-# v2: sidecar serialized-executable tier
-# v3: sharded tier (AOT-serialized jax.stages.Compiled for multi-device
-#     placements) + explicit topology recorded per payload
-_FORMAT_VERSION = 3
-_MAX_REASONS = 20  # keep fallback/skip reason lists bounded
-
-
-def _flat_out_structure(out_info: Any) -> tuple[int, bool] | None:
-    """(n_outputs, is_single_leaf) when the output pytree is a leaf or a
-    flat tuple/list of leaves; None for nested structures (not cached —
-    the raw executable returns a flat list we could not fold back)."""
-    leaves, treedef = jax.tree_util.tree_flatten(out_info)
-    if not leaves:
-        return None
-    if len(leaves) == 1 and treedef == jax.tree_util.tree_structure(leaves[0]):
-        return 1, True
-    if treedef == jax.tree_util.tree_structure(tuple(leaves)):
-        return len(leaves), False
-    if treedef == jax.tree_util.tree_structure(list(leaves)):
-        return len(leaves), False
-    return None
+# v4: one tier — jax.experimental.serialize_executable for every entry,
+#     payload records the executable's device ids
+_FORMAT_VERSION = 4
+_MAX_REASONS = 20  # keep the fallback reason list bounded
 
 
 def _source_digest() -> str:
@@ -145,16 +99,6 @@ def _topology_token() -> str:
     return f"{kind}x{len(devices)}p{jax.process_count()}"
 
 
-def _topology_dict() -> dict:
-    """The explicit topology a sharded payload was compiled for."""
-    devices = jax.devices()
-    return {
-        "kind": devices[0].device_kind,
-        "devices": len(devices),
-        "processes": jax.process_count(),
-    }
-
-
 def _jaxlib_version() -> str:
     try:
         import jaxlib
@@ -165,8 +109,7 @@ def _jaxlib_version() -> str:
 
 
 class HloDiskCache:
-    """Two-tier persistent artifact cache: serialized executables over
-    lowered HLO text, both keyed per compile-cache key."""
+    """Persistent executable cache keyed per compile-cache key."""
 
     def __init__(self, root: str) -> None:
         backend = jax.default_backend()
@@ -177,25 +120,14 @@ class HloDiskCache:
         )
         os.makedirs(self.root, exist_ok=True)
         self.hits = 0  # warm loads that produced a working executable
-        self.exe_hits = 0  # ...of which tier 1: zero XLA compilation
-        self.hlo_hits = 0  # ...of which tier 2: one compile, no retrace
         self.misses = 0  # lookups that fell back to tracing
-        self.stores = 0  # payloads written (HLO text + characterization)
-        self.exe_stores = 0  # ...with a serialized-executable sidecar
-        self.xla_compiles = 0  # compilations this cache triggered (tier 2)
+        self.stores = 0  # executables (+ characterization) written
         # Fallback diagnostics: a *fallback* is a present-but-unusable
         # entry (corrupt payload, stale format, failed trial call) — a
         # missing file is just a cold miss and is not recorded here.
-        self.fallback_count = 0  # fell all the way back to retracing
+        self.fallback_count = 0
         self.fallback_reasons: list[str] = []  # capped at _MAX_REASONS
         self.last_fallback: str | None = None
-        self.exe_fallbacks = 0  # tier 1 unusable, degraded to tier 2
-        self.last_exe_fallback: str | None = None
-        # Lookups the engine declined to attempt (multi-device placements):
-        # counted here so the skip is visible in summary(), not silent.
-        self.skips = 0
-        self.skip_reasons: list[str] = []  # capped at _MAX_REASONS
-        self.last_skip: str | None = None
         # Autotune-winner sidecar traffic (store_tuned / load_tuned).
         self.tune_hits = 0  # winners restored (warm run: zero trials)
         self.tune_stores = 0  # winners persisted
@@ -207,30 +139,17 @@ class HloDiskCache:
     def _exe_path(self, key: tuple) -> str:
         return self._path(key)[: -len(".json")] + ".exe"
 
-    @staticmethod
-    def _reason(key: tuple, exc: BaseException) -> str:
-        name = key[0] if key else "?"
-        reason = " ".join(f"{name}: {type(exc).__name__}: {exc}".split())
-        return reason if len(reason) <= 200 else reason[:197] + "..."
+    def _tune_path(self, key: tuple) -> str:
+        return self._path(key)[: -len(".json")] + ".tune.json"
 
     def _note_fallback(self, key: tuple, exc: BaseException) -> None:
-        reason = self._reason(key, exc)
+        name = key[0] if key else "?"
+        reason = " ".join(f"{name}: {type(exc).__name__}: {exc}".split())
+        reason = reason if len(reason) <= 200 else reason[:197] + "..."
         self.fallback_count += 1
         self.last_fallback = reason
         if len(self.fallback_reasons) < _MAX_REASONS:
             self.fallback_reasons.append(reason)
-
-    def _note_exe_fallback(self, key: tuple, exc: BaseException) -> None:
-        self.exe_fallbacks += 1
-        self.last_exe_fallback = self._reason(key, exc)
-
-    def note_skip(self, key: tuple, reason: str) -> None:
-        """Record a lookup the caller declined to attempt (and why)."""
-        name = key[0] if key else "?"
-        self.skips += 1
-        self.last_skip = f"{name}: {reason}"
-        if len(self.skip_reasons) < _MAX_REASONS:
-            self.skip_reasons.append(self.last_skip)
 
     def counter_dict(self) -> dict[str, int]:
         """The numeric counter totals as a plain dict — what the engine
@@ -239,15 +158,9 @@ class HloDiskCache:
         Numbers only; the reason strings stay on the object / summary()."""
         return {
             "hits": self.hits,
-            "exe_hits": self.exe_hits,
-            "hlo_hits": self.hlo_hits,
             "misses": self.misses,
             "stores": self.stores,
-            "exe_stores": self.exe_stores,
-            "xla_compiles": self.xla_compiles,
             "fallback_count": self.fallback_count,
-            "exe_fallbacks": self.exe_fallbacks,
-            "skips": self.skips,
             "tune_hits": self.tune_hits,
             "tune_stores": self.tune_stores,
         }
@@ -255,23 +168,13 @@ class HloDiskCache:
     def summary(self) -> str:
         """One-line cache diagnosis for verbose engine output."""
         line = (
-            f"hlocache: hits={self.hits} exe_hits={self.exe_hits} "
-            f"hlo_hits={self.hlo_hits} misses={self.misses} "
-            f"stores={self.stores} exe_stores={self.exe_stores} "
-            f"xla_compiles={self.xla_compiles} "
-            f"fallbacks={self.fallback_count} exe_fallbacks={self.exe_fallbacks} "
+            f"hlocache: hits={self.hits} misses={self.misses} "
+            f"stores={self.stores} fallbacks={self.fallback_count} "
             f"tune_hits={self.tune_hits} tune_stores={self.tune_stores}"
         )
-        if self.skips:
-            line += f" skips={self.skips} last_skip=[{self.last_skip}]"
-        if self.last_exe_fallback is not None:
-            line += f" last_exe_fallback=[{self.last_exe_fallback}]"
         if self.last_fallback is not None:
             line += f" last_fallback=[{self.last_fallback}]"
         return line
-
-    def _tune_path(self, key: tuple) -> str:
-        return self._path(key)[: -len(".json")] + ".tune.json"
 
     # -- autotune winners ----------------------------------------------------
 
@@ -317,107 +220,25 @@ class HloDiskCache:
 
     # -- store -------------------------------------------------------------
 
-    def store(
-        self,
-        key: tuple,
-        lowered: Any,
-        compiled: Any,
-        name: str,
-        *,
-        sharded: bool = False,
-    ) -> None:
-        """Persist one compile: the HLO-text payload, and — when the
-        backend supports AOT serialization — the executable sidecar.
-        Best-effort: outputs that are not a flat tuple of arrays, or
-        analyses this backend does not expose, simply skip the store — a
-        miss next run, never an error this run. ``sharded`` routes
-        multi-device programs through the sharded tier (the whole
-        ``jax.stages.Compiled`` serialized, no HLO-text fallback)."""
-        if sharded:
-            self._store_sharded(key, compiled, name)
-            return
-        try:
-            out = _flat_out_structure(lowered.out_info)
-            if out is None:
-                return
-            n_outputs, single = out
-            from repro.core.metrics import (
-                collective_bytes_from_hlo,
-                cost_analysis_dict,
-            )
-            from repro.core.harness import _memory_analysis_dict
-
-            text = lowered.as_text()
-            payload = {
-                "format": _FORMAT_VERSION,
-                "name": name,
-                "hlo": text,
-                "n_outputs": n_outputs,
-                "single": single,
-                # jax.jit prunes arguments the program never reads; the raw
-                # executable then wants only the kept ones. None = keep all
-                # (also the right answer when the internal attr moves — the
-                # trial call catches any drift).
-                "kept_args": _kept_arg_indices(compiled),
-                "cost": cost_analysis_dict(compiled),
-                "memory": _memory_analysis_dict(compiled),
-                "collective_bytes": collective_bytes_from_hlo(compiled.as_text()),
-            }
-            # Executable sidecar first: if serialization is unsupported the
-            # payload alone still buys tier 2; if the payload write then
-            # fails, an orphan .exe is unreachable (loads start at .json).
-            exe_path = self._exe_path(key)
-            try:
-                blob = _serialize_executable(compiled)
-                tmp = exe_path + ".tmp"
-                with open(tmp, "wb") as f:
-                    f.write(blob)
-                os.replace(tmp, exe_path)
-                self.exe_stores += 1
-            except Exception:  # noqa: BLE001 — tier 1 is an accelerator
-                for stale in (exe_path + ".tmp", exe_path):
-                    # Drop both the torn tmp and any stale sidecar: never
-                    # pair an old executable with new lowering text.
-                    if os.path.exists(stale):
-                        try:
-                            os.remove(stale)
-                        except OSError:
-                            pass
-            path = self._path(key)
-            tmp = path + ".tmp"
-            with open(tmp, "w") as f:
-                json.dump(payload, f)
-            os.replace(tmp, path)
-            self.stores += 1
-        except Exception:  # noqa: BLE001 — persistence is advisory
-            return
-
-    def _store_sharded(self, key: tuple, compiled: Any, name: str) -> None:
-        """Persist one multi-device compile: the AOT-serialized
-        ``jax.stages.Compiled`` (sharding, argument pruning, and pytree
-        call convention all round-trip) plus a payload recording the
-        explicit topology it was compiled for. The sidecar is written
-        first — a payload without its blob is useless here (there is no
-        HLO-text tier for sharded entries), so a failed blob write stores
-        nothing and a failed payload write removes the orphan."""
+    def store(self, key: tuple, compiled: Any, name: str) -> None:
+        """Persist one compile: the serialized executable, then the payload
+        (characterization + device ids). A payload without its blob is
+        useless, so a failed blob write stores nothing and a failed payload
+        write removes the orphan. Best-effort: a program that does not
+        serialize simply misses next run, never errors this run."""
         exe_path = self._exe_path(key)
         try:
-            from repro.core.harness import _memory_analysis_dict
-            from repro.core.metrics import (
-                collective_bytes_from_hlo,
-                cost_analysis_dict,
-            )
-
             payload = {
                 "format": _FORMAT_VERSION,
                 "name": name,
-                "sharded": True,
-                "topology": _topology_dict(),
+                "device_ids": [
+                    d.id for d in compiled.runtime_executable().local_devices()
+                ],
                 "cost": cost_analysis_dict(compiled),
                 "memory": _memory_analysis_dict(compiled),
                 "collective_bytes": collective_bytes_from_hlo(compiled.as_text()),
             }
-            blob = _serialize_sharded(compiled)
+            blob = _serialize(compiled)
             tmp = exe_path + ".tmp"
             with open(tmp, "wb") as f:
                 f.write(blob)
@@ -428,7 +249,6 @@ class HloDiskCache:
                 json.dump(payload, f)
             os.replace(tmp, path)
             self.stores += 1
-            self.exe_stores += 1
         except Exception:  # noqa: BLE001 — persistence is advisory
             for stale in (exe_path + ".tmp", exe_path):
                 if os.path.exists(stale):
@@ -436,26 +256,16 @@ class HloDiskCache:
                         os.remove(stale)
                     except OSError:
                         pass
-            return
 
     # -- load --------------------------------------------------------------
 
     def load(
-        self, key: tuple, args: tuple, *, sharded: bool = False
+        self, key: tuple, args: tuple
     ) -> tuple[Callable[..., Any], CompiledInfo] | None:
-        """Restore one compile from disk, best tier first.
-
-        Tier 1 deserializes the stored executable (no retrace, no XLA
-        compile); tier 2 compiles the stored HLO text directly (no
-        retrace). Either way the memoized characterization is rebuilt and
-        one trial execution validates the call convention; any failure
-        degrades to the next tier and — unless the entry simply wasn't
-        there — is counted and named in the fallback diagnostics.
-        ``sharded`` loads go through the sharded tier only: the stored
-        ``jax.stages.Compiled`` is deserialized under the recorded
-        topology (a mismatch is a counted fallback) with no HLO-text
-        fallback — recompiling sharded text would target one device.
-        Returns None when the caller must retrace."""
+        """Restore one compile from disk onto the devices it was compiled
+        for, trial-call it, and rebuild the memoized characterization.
+        Returns None when the caller must retrace; a present-but-unusable
+        entry is counted and named in the fallback diagnostics."""
         path = self._path(key)
         if not os.path.exists(path):
             self.misses += 1  # cold miss: nothing to fall back from
@@ -465,37 +275,16 @@ class HloDiskCache:
                 payload = json.load(f)
             if payload.get("format") != _FORMAT_VERSION:
                 raise ValueError("stale cache format")
-            if bool(payload.get("sharded", False)) != sharded:
-                raise ValueError(
-                    "entry tier mismatch: stored "
-                    f"sharded={payload.get('sharded', False)!r}, "
-                    f"requested sharded={sharded!r}"
-                )
-            if sharded:
-                topology = payload.get("topology")
-                if topology != _topology_dict():
-                    raise ValueError(
-                        f"topology mismatch: entry compiled for {topology}, "
-                        f"host is {_topology_dict()}"
-                    )
-                with open(self._exe_path(key), "rb") as f:
-                    blob = f.read()
-                executable = _deserialize_sharded(blob)
-                jax.block_until_ready(executable(*args))  # trial call
-                via_exe = True
-            else:
-                executable = self._load_single(key, payload, args)
-                via_exe = executable is not None
-                if executable is None:
-                    n_outputs = int(payload["n_outputs"])
-                    single = bool(payload["single"])
-                    kept = payload.get("kept_args")
-                    kept = [int(i) for i in kept] if kept is not None else None
-                    executable = _compile_text(
-                        payload["hlo"], n_outputs, single, kept
-                    )
-                    self.xla_compiles += 1
-                    jax.block_until_ready(executable(*args))  # trial call
+            by_id = {d.id: d for d in jax.devices()}
+            missing = [i for i in payload["device_ids"] if i not in by_id]
+            if missing:
+                raise ValueError(f"entry names devices {missing} absent here")
+            with open(self._exe_path(key), "rb") as f:
+                blob = f.read()
+            executable = _deserialize(
+                blob, [by_id[i] for i in payload["device_ids"]]
+            )
+            jax.block_until_ready(executable(*args))  # trial call
             info = CompiledInfo(
                 name=payload["name"],
                 cost=dict(payload["cost"]),
@@ -511,118 +300,24 @@ class HloDiskCache:
             self._note_fallback(key, e)
             return None
         self.hits += 1
-        if via_exe:
-            self.exe_hits += 1
-        else:
-            self.hlo_hits += 1
         return executable, info
 
-    def _load_single(
-        self, key: tuple, payload: dict, args: tuple
-    ) -> Callable[..., Any] | None:
-        """Tier-1 attempt for a single-device entry: the raw serialized
-        executable, trial-called; None (with the exe fallback counted)
-        when the blob is missing or no longer deserializes — the caller
-        then degrades to tier 2."""
-        exe_path = self._exe_path(key)
-        if not os.path.exists(exe_path):
-            return None
-        n_outputs = int(payload["n_outputs"])
-        single = bool(payload["single"])
-        kept = payload.get("kept_args")
-        kept = [int(i) for i in kept] if kept is not None else None
-        try:
-            with open(exe_path, "rb") as f:
-                blob = f.read()
-            executable = _deserialize_executable(blob, n_outputs, single, kept)
-            jax.block_until_ready(executable(*args))  # trial call
-        except Exception as e:  # noqa: BLE001 — degrade to tier 2
-            self._note_exe_fallback(key, e)
-            return None
-        return executable
 
-
-def _kept_arg_indices(compiled: Any) -> list[int] | None:
-    """Flat indices of the arguments the compiled program actually reads
-    (jax.jit prunes unused ones from the XLA signature), or None for
-    all-kept / attr-unavailable — best-effort, backstopped by the trial
-    call at load time."""
-    try:
-        kept = compiled._executable._kept_var_idx
-        return sorted(int(i) for i in kept)
-    except Exception:  # noqa: BLE001 — internal attr, may move across jax
-        return None
-
-
-def _wrap_executable(
-    exe: Any, n_outputs: int, single: bool, kept: list[int] | None = None
-) -> Callable[..., Any]:
-    """Adapt a raw loaded executable to the jitted-call convention the
-    engine's timer/serve stages use (flat args in, folded outputs out,
-    pruned args dropped)."""
-
-    def call(*args: Any) -> Any:
-        flat = [
-            a if isinstance(a, jax.Array) else jnp.asarray(a)
-            for a in jax.tree_util.tree_leaves(args)
-        ]
-        if kept is not None:
-            flat = [flat[i] for i in kept]
-        outs = exe.execute(flat)
-        if len(outs) != n_outputs:
-            raise RuntimeError(
-                f"cached executable returned {len(outs)} outputs, "
-                f"expected {n_outputs}"
-            )
-        return outs[0] if single else tuple(outs)
-
-    return call
-
-
-def _serialize_sharded(compiled: Any) -> bytes:
-    """AOT-serialize a (possibly multi-device) ``jax.stages.Compiled``
-    whole: executable payload plus input/output pytree defs. Unlike the
-    raw-executable tier, deserializing this reproduces sharded outputs
-    and the jit call convention (pruned args included)."""
+def _serialize(compiled: Any) -> bytes:
+    """AOT-serialize a ``jax.stages.Compiled`` whole: executable payload
+    plus input/output pytree defs."""
     from jax.experimental import serialize_executable as jse
 
     payload, in_tree, out_tree = jse.serialize(compiled)
     return pickle.dumps((payload, in_tree, out_tree))
 
 
-def _deserialize_sharded(blob: bytes) -> Callable[..., Any]:
-    """Sharded tier: bytes → a loaded ``jax.stages.Compiled`` (callable
+def _deserialize(blob: bytes, devices: list) -> Callable[..., Any]:
+    """Bytes → a loaded ``jax.stages.Compiled`` on ``devices`` (callable
     with the original arguments), with zero XLA compilation."""
     from jax.experimental import serialize_executable as jse
 
     payload, in_tree, out_tree = pickle.loads(blob)
-    return jse.deserialize_and_load(payload, in_tree, out_tree)
-
-
-def _serialize_executable(compiled: Any) -> bytes:
-    """AOT-serialize a ``jax.stages.Compiled``'s loaded executable."""
-    from jax.extend import backend as jex_backend
-
-    exe = compiled.runtime_executable()
-    return jex_backend.get_backend().serialize_executable(exe)
-
-
-def _deserialize_executable(
-    blob: bytes, n_outputs: int, single: bool, kept: list[int] | None = None
-) -> Callable[..., Any]:
-    """Tier 1: bytes → runnable, with zero XLA compilation."""
-    from jax.extend import backend as jex_backend
-
-    exe = jex_backend.get_backend().deserialize_executable(blob)
-    return _wrap_executable(exe, n_outputs, single, kept)
-
-
-def _compile_text(
-    text: str, n_outputs: int, single: bool, kept: list[int] | None = None
-) -> Callable[..., Any]:
-    """Tier 2: stored StableHLO text → runnable (one XLA compilation,
-    no Python retrace)."""
-    from jax.extend import backend as jex_backend
-
-    exe = jex_backend.get_backend().compile(text)
-    return _wrap_executable(exe, n_outputs, single, kept)
+    return jse.deserialize_and_load(
+        payload, in_tree, out_tree, execution_devices=devices
+    )

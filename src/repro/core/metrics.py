@@ -25,6 +25,8 @@ from typing import Any, Mapping
 
 __all__ = [
     "TPUv5e",
+    "PEAKS_BY_KIND",
+    "device_peaks",
     "RooflineTerms",
     "roofline_terms",
     "cost_analysis_dict",
@@ -36,14 +38,8 @@ __all__ = [
 
 
 def cost_analysis_dict(compiled: Any) -> dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across jax versions.
-
-    Older jax returns a one-element list of dicts; newer returns the dict
-    directly. Non-numeric entries are dropped.
-    """
+    """``compiled.cost_analysis()`` with non-numeric entries dropped."""
     raw = compiled.cost_analysis()
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else {}
     return {k: float(v) for k, v in dict(raw or {}).items() if isinstance(v, (int, float))}
 
 
@@ -59,8 +55,8 @@ class _HW:
     vmem_bytes: float  # on-chip vector memory
 
 
-# The assigned roofline target: TPU v5e (197 TFLOP/s bf16, 16 GiB @ 819 GB/s,
-# ~50 GB/s per ICI link).
+# TPU v5e (Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 16 GiB HBM at 819 GB/s; ~50 GB/s per ICI link).
 TPUv5e = _HW(
     name="tpu_v5e",
     peak_bf16_flops=197e12,
@@ -69,6 +65,32 @@ TPUv5e = _HW(
     ici_bw=50e9,
     vmem_bytes=128 * 1024**2,
 )
+
+# Per-chip peaks keyed by ``jax.Device.device_kind``.
+PEAKS_BY_KIND: dict[str, _HW] = {"TPU v5 lite": TPUv5e}
+
+
+def device_peaks(device: Any = None) -> _HW:
+    """The roofline peaks of ``device`` (default: the first device).
+
+    A TPU whose kind is not in :data:`PEAKS_BY_KIND` is an error, never a
+    default. Non-TPU backends (the CPU test host) get the static
+    projection onto the v5e entry; their run metadata says which backend
+    ran, so the projection is never mistaken for a device measurement.
+    """
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return TPUv5e
+    try:
+        return PEAKS_BY_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device kind {device.device_kind!r}; "
+            f"known: {sorted(PEAKS_BY_KIND)}"
+        ) from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,9 +137,12 @@ def roofline_terms(
     cost: Mapping[str, float],
     *,
     collective_bytes: float = 0.0,
-    hw: _HW = TPUv5e,
+    hw: _HW | None = None,
 ) -> RooflineTerms:
     """Build roofline terms from ``compiled.cost_analysis()`` output.
+
+    ``hw`` defaults to :func:`device_peaks` of the device this process runs
+    on.
 
     ``cost_analysis`` runs *after* SPMD partitioning, so flops/bytes are
     per-device numbers (verified in tests/test_metrics.py against a matmul of
@@ -125,6 +150,8 @@ def roofline_terms(
     an HBM-roundtrip upper bound that double counts what stays resident in
     VMEM — acceptable for a static bound, and consistent across benchmarks.
     """
+    if hw is None:
+        hw = device_peaks()
     flops = float(cost.get("flops", 0.0))
     # Sum every "bytes accessed..." key once; XLA splits operand/output
     # traffic into e.g. 'bytes accessed', 'bytes accessed0{}', 'utilization..'.

@@ -10,7 +10,10 @@ Rodinia-style per-parameter overrides on top. Preset intents:
      suite run use in this container),
 - 1: laptop-scale,
 - 2: single accelerator,
-- 3: large single accelerator (fills a v5e),
+- 3: large single accelerator — the one-chip size ``chip_smoke.py`` runs
+     on a TPU v5e. It does not fill the chip: the largest input is
+     ``dropout``'s 16384×8192 f32, 512 MiB of the v5e's 16 GiB, and no
+     preset-3 program needs more than about 1.3 GiB of device memory,
 - 4: future headroom (explicitly allowed to exceed today's devices so the
      suite "stays relevant as problem sizes grow" — §III-B).
 """

@@ -505,7 +505,7 @@ class RunMetadata:
     tune: bool = False  # whether the autotune stage was enabled
     # Observability (schema v8), stamped at end of run — None at capture
     # time and on pre-v8 reports. cache_stats is the HloDiskCache counter
-    # totals (exe_hits/hlo_hits/xla_compiles/fallback_count/skips/...),
+    # totals (hits/misses/stores/fallback_count/...),
     # present whenever the run had a --cache-dir, so a committed report
     # says whether the run was warm without needing verbose stdout.
     # counters is the obs layer's counter snapshot, present when tracing

@@ -25,11 +25,12 @@ thread per lane, with dispatch-overhead and per-lane QPS columns);
 ``--slo-us`` adds a latency SLO and the ``goodput_qps`` column;
 ``--colocate NAME`` serves each workload against a partner benchmark and
 records both tenants' slowdown vs their isolated baselines.
-``--cache-dir`` persists compile artifacts across processes — two tiers:
-serialized executables (warm runs skip tracing AND XLA compilation — the
-zero-compile warm start) over lowered HLO text (skips retracing only);
-the CLI always prints the cache's hit/fallback/skip summary so a cache
-that never hits is visible.
+``--cache-dir`` persists serialized executables across processes (warm
+runs skip tracing AND XLA compilation — the zero-compile warm start); the
+CLI always prints the cache's hit/fallback summary so a cache that never
+hits is visible. JAX's own persistent compilation cache lives in
+``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<cache-dir>/jax-persistent``,
+else ``<checkout>/.jax_cache``.
 
 Timing flags: sync-mode timing (synchronize every call) always runs and
 fills ``us_per_call``; ``--timing-window K`` (default 4; 1 disables)
@@ -63,7 +64,7 @@ import argparse
 import sys
 from typing import Any, Mapping, Sequence
 
-from repro.core.engine import Engine
+from repro.core.engine import Engine, enable_compile_cache
 from repro.obs import Tracer
 from repro.core.plan import (
     IMPLS,
@@ -496,7 +497,7 @@ def main(argv: Sequence[str] | None = None) -> int:
                          "(implies --serve closed)")
     ap.add_argument("--cache-dir", type=str, default=None,
                     help="persist compile artifacts here (serialized "
-                         "executables + lowered HLO text, keyed by compile-"
+                         "executables, keyed by compile-"
                          "cache key, versioned by jax/jaxlib/backend/"
                          "topology) so warm runs skip retracing and XLA "
                          "compilation entirely; a CI accelerator — warm-run "
@@ -512,9 +513,8 @@ def main(argv: Sequence[str] | None = None) -> int:
                          "stage spans plus per-lane serve requests and "
                          "per-queue batcher flushes as separate tracks")
     args = ap.parse_args(argv)
+    enable_compile_cache(args.cache_dir)
     tracer = Tracer() if args.trace_out else None
-    # Engine(cache_dir=...) also points jax's own persistent compilation
-    # cache at the directory, so input-builder compiles warm too.
     engine = (
         Engine(cache_dir=args.cache_dir, tracer=tracer)
         if (args.cache_dir or tracer is not None)

@@ -52,7 +52,7 @@ from repro.serve.latency import (
     stats_from_completions,
 )
 
-__all__ = ["DistLatencyStats", "run_distributed"]
+__all__ = ["DistLatencyStats", "refuse_children_on_device", "run_distributed"]
 
 # How long one client may spend building + compiling before the run is
 # declared wedged. Generous: a cold multi-device compile on a loaded CI
@@ -61,6 +61,25 @@ _READY_TIMEOUT_S = 600.0
 # Seconds between the Start broadcast and the shared epoch: long enough
 # for every client to receive the frame and wake its sleep loop.
 _START_LEAD_S = 0.3
+
+
+def refuse_children_on_device() -> None:
+    """Raise ``PlanError`` unless this process runs on the CPU backend.
+
+    Every client process builds its own ``Engine`` and compiles on the
+    device. An accelerator belongs to one process at a time, and this
+    process already holds it, so on anything but the CPU the children
+    would fail or hang."""
+    import jax
+
+    from repro.core.plan import PlanError
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise PlanError(
+            f"client processes are CPU-only: each child would need the "
+            f"{backend} device this process holds"
+        )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,7 +91,7 @@ class DistLatencyStats(LatencyStats):
     client_procs: int = 0
     proc_qps: tuple[float, ...] | None = None  # achieved QPS per process
     # Summed HloDiskCache counters across the client processes (None when
-    # the run had no cache dir): misses == xla_compiles == 0 here is the
+    # the run had no cache dir): misses == 0 here is the
     # "warm distributed run compiled nothing anywhere" assertion.
     client_cache_counters: dict | None = None
 
@@ -195,6 +214,7 @@ def run_distributed(
     n = int(serve.client_procs)
     if n < 1:
         raise ValueError(f"run_distributed needs client_procs >= 1, got {n}")
+    refuse_children_on_device()
     serve_fields = {
         f.name: getattr(serve, f.name) for f in dataclasses.fields(type(serve))
     }

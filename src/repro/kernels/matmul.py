@@ -40,8 +40,15 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    a, b = a_ref[...], b_ref[...]
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        a, b,
+        # f32 operands contract at full f32 precision, matching the
+        # reference; Mosaic refuses that setting for bf16 operands.
+        precision=(
+            jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+        ),
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(pl.program_id(2) == k_steps - 1)

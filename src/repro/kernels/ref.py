@@ -23,8 +23,12 @@ __all__ = [
 
 
 def matmul_ref(a: jax.Array, b: jax.Array) -> jax.Array:
-    """C = A @ B with fp32 accumulation, cast back to A's dtype."""
-    out = jnp.dot(a, b, preferred_element_type=jnp.float32)
+    """C = A @ B with fp32 accumulation, cast back to A's dtype. Full
+    precision: on TPU the default would round f32 operands to bf16."""
+    out = jnp.dot(
+        a, b, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
     return out.astype(a.dtype)
 
 

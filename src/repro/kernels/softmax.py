@@ -3,8 +3,10 @@
 Two internal passes over column chunks held in VMEM: pass 1 accumulates the
 running max and sum-of-exponentials (online softmax, numerically safe for
 long rows); pass 2 writes the normalized values. Rows are tiled over the
-grid; columns are chunked inside the kernel so arbitrarily wide class
-dimensions never exceed the VMEM block.
+grid; columns are chunked inside the kernel. A block holds whole padded
+rows, so the default row count per block is derived from the row width:
+the double-buffered input and output blocks stay inside a fixed VMEM
+budget at any class count.
 """
 
 from __future__ import annotations
@@ -19,15 +21,30 @@ __all__ = ["softmax_pallas", "tune_space"]
 
 
 def tune_space() -> tuple[dict, ...]:
-    """Autotune candidates (first entry = the kernel's defaults)."""
+    """Autotune candidates (first entry = the kernel's defaults). Row
+    counts are left to the width-derived default except one small fixed
+    count, so every candidate fits VMEM at every width."""
     return (
-        {"block_rows": 256, "block_cols": 512},
-        {"block_rows": 128, "block_cols": 512},
-        {"block_rows": 512, "block_cols": 256},
-        {"block_rows": 256, "block_cols": 1024},
+        {"block_cols": 512},
+        {"block_cols": 256},
+        {"block_cols": 1024},
+        {"block_rows": 32, "block_cols": 512},
     )
 
 _NEG_INF = -1e30
+# Bytes the pipelined blocks may take: input + output, each double-
+# buffered. Half of the 16 MiB scoped-VMEM default, leaving room for the
+# kernel's column-chunk temporaries.
+_BLOCK_VMEM_BYTES = 8 * 1024 * 1024
+_MAX_BLOCK_ROWS = 256
+
+
+def _default_block_rows(width: int, itemsize: int) -> int:
+    """Rows per block for rows of ``width`` padded elements: the most (a
+    multiple of 8, at most 256) whose four pipelined blocks fit
+    ``_BLOCK_VMEM_BYTES``."""
+    rows = _BLOCK_VMEM_BYTES // (4 * width * itemsize)
+    return max(8, min(_MAX_BLOCK_ROWS, rows // 8 * 8))
 
 
 def _softmax_kernel(x_ref, o_ref, *, block_c: int, c_valid: int):
@@ -68,7 +85,7 @@ def _softmax_kernel(x_ref, o_ref, *, block_c: int, c_valid: int):
 def softmax_pallas(
     x: jax.Array,  # (..., C) — flattened to (R, C)
     *,
-    block_rows: int = 256,
+    block_rows: int | None = None,  # None: _default_block_rows(width)
     block_cols: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
@@ -76,9 +93,12 @@ def softmax_pallas(
     C = orig_shape[-1]
     x2 = x.reshape(-1, C)
     R = x2.shape[0]
-    br = min(block_rows, R)
     bc = min(block_cols, C)
-    pr, pc = (-R) % br, (-C) % bc
+    pc = (-C) % bc
+    if block_rows is None:
+        block_rows = _default_block_rows(C + pc, x.dtype.itemsize)
+    br = min(block_rows, R)
+    pr = (-R) % br
     if pr or pc:
         x2 = jnp.pad(x2, ((0, pr), (0, pc)))
     Rp, Cp = x2.shape

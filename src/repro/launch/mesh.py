@@ -8,6 +8,7 @@ this module never touches jax device state (the dry-run must set
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "POD_SHAPE", "MULTI_POD_SHAPE"]
 
@@ -18,7 +19,9 @@ MULTI_POD_SHAPE = (2, 16, 16)
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTI_POD_SHAPE if multi_pod else POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the activation sharder constrains with
+    # with_sharding_constraint, which refuses Explicit mesh axes.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(multi_pod: bool) -> tuple[str, ...]:

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.runtime.sharding import pvary, shard_map
 
 __all__ = ["gpipe_forward"]
 
@@ -64,10 +63,10 @@ def gpipe_forward(
             )
             return act_next, emitted
 
-        x0 = pvary(jnp.zeros_like(x_mb[0]), (axis,))
+        x0 = jax.lax.pcast(jnp.zeros_like(x_mb[0]), (axis,), to="varying")
         _, results = jax.lax.scan(tick, x0, jnp.arange(ticks))
         return results[n_stages - 1 :]  # (M, mb, ...)
 
     in_specs = (P(axis), P())  # params stage-sharded; microbatches replicated
     out_specs = P()
-    return shard_map(pipelined, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.shard_map(pipelined, mesh=mesh, in_specs=in_specs, out_specs=out_specs)

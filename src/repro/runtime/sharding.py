@@ -22,24 +22,6 @@ from typing import Any
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # newer jax exports shard_map at top level with a `check_vma` kwarg
-    shard_map = jax.shard_map
-except AttributeError:  # older jax keeps it in experimental as `check_rep`
-    from functools import wraps
-
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    @wraps(_experimental_shard_map)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _experimental_shard_map(*args, **kwargs)
-
-
-# jax.lax.pvary (varying-axis annotation for the vma checker) only exists on
-# newer jax; it is semantically an identity, so fall back to one.
-pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 __all__ = [
     "ShardingRules",
     "param_pspecs",
@@ -52,8 +34,6 @@ __all__ = [
     "workload_pspecs",
     "shard_applies",
     "place_args",
-    "shard_map",
-    "pvary",
 ]
 
 # name -> ordered candidate shard dims (on the UNstacked leaf shape).

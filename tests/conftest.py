@@ -6,6 +6,12 @@ import pytest
 
 import jax
 
+# Tests drive the entry points in-process, and those place JAX's persistent
+# compilation cache (repro.core.engine.enable_compile_cache). Keep that
+# cache off here: the xdist workers would share one directory, and its
+# writes are not atomic.
+jax.config.update("jax_enable_compilation_cache", False)
+
 
 @pytest.fixture
 def rng():

@@ -54,9 +54,8 @@ def test_int8_error_feedback_compression():
         def f(gsh, esh):
             out, err = comp.reduce_mean({"w": gsh}, {"w": esh})
             return out["w"], err["w"]
-        from repro.runtime.sharding import shard_map
-        fm = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                               out_specs=(P(), P("pod")), check_vma=False))
+        fm = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                                   out_specs=(P(), P("pod")), check_vma=False))
         want = np.asarray(g).mean(0)
         # single shot: bounded quantization error (int8 against a shared
         # max-scale: ~scale/2 per element)
@@ -81,7 +80,7 @@ def test_production_sharding_on_mini_mesh():
     smoke config compile AND execute with real sharded buffers."""
     _run("""
         import functools, numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         import dataclasses
         from repro.configs import get_smoke_config
         from repro.models import Model
@@ -91,7 +90,8 @@ def test_production_sharding_on_mini_mesh():
             cache_pspecs, make_activation_sharder, param_pspecs)
         from repro.runtime.steps import make_train_step
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
         for arch in ("granite-3-8b", "mixtral-8x22b", "jamba-1.5-large-398b", "xlstm-350m"):
             cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
             rules = ShardingRules(mesh=mesh, data_axes=("pod", "data"), seq_shard=True)

@@ -227,3 +227,19 @@ def test_run_sections_rejects_unknown_section(capsys):
     err = capsys.readouterr().err
     assert "bogus" in err
     assert "table1" in err and "fig5" in err  # lists the valid sections
+
+
+def test_outputs_reuse_the_run_executable_and_match_a_plain_jit():
+    import jax
+    import numpy as np
+
+    eng = Engine()
+    plan = _plan(names=("gemm_f32_nn",), include_backward=False)
+    eng.run(plan)
+    misses = eng.cache.misses
+    spec = get_benchmark("gemm_f32_nn")
+    got = eng.outputs(spec, plan, 1)
+    assert eng.cache.misses == misses  # served from the run's cache entry
+    w = spec.build_preset(0)
+    want = jax.jit(w.fn)(*w.make_inputs(plan.seed))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)

@@ -195,8 +195,8 @@ def test_tuned_winner_persists_and_warm_run_skips_the_sweep(tmp_path, monkeypatc
     assert rec2.tune_trials == 0 and rec2.tune_trials_us == 0.0
     assert rec2.tuned_params == rec.tuned_params
     assert warm.disk_cache.tune_hits == 1 and warm.disk_cache.tune_stores == 0
-    assert warm.disk_cache.misses == 0 and warm.disk_cache.xla_compiles == 0
-    assert warm.disk_cache.exe_hits == warm.disk_cache.hits > 0
+    assert warm.disk_cache.misses == 0 and warm.disk_cache.fallback_count == 0
+    assert warm.disk_cache.hits > 0
 
 
 def test_tune_is_a_noop_for_xla_and_untunable_passes():

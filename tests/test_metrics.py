@@ -65,9 +65,7 @@ def test_real_psum_hlo_is_parsed():
     def f(x):
         return jax.lax.psum(x, "d")
 
-    from repro.runtime.sharding import shard_map
-
-    fm = shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P())
+    fm = jax.shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P())
     c = jax.jit(fm).lower(jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile()
     # single-device: collective may be optimized away; parsing must not crash
     assert collective_bytes_from_hlo(c.as_text()) >= 0.0

@@ -8,6 +8,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.configs import get_smoke_config
 from repro.models import Model
@@ -130,7 +131,9 @@ def test_dp_only_sharder_never_reuses_axes():
     from repro.runtime.sharding import ShardingRules, make_activation_sharder
 
     rules = ShardingRules(
-        mesh=jax.make_mesh((1, 1), ("data", "model")),
+        mesh=jax.make_mesh(
+            (1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+        ),
         data_axes=("data", "model"),
         seq_shard=True,
     )
