@@ -37,13 +37,34 @@ The ambient tracer (:func:`current_tracer` / :func:`use_tracer`) lets the
 serve layer reach the engine's tracer without threading a parameter
 through every client/lane signature; the default is :data:`NULL_TRACER`.
 
-Everything here is stdlib-only and imports nothing from ``repro``.
+The JAX profiler as a second sink
+---------------------------------
+
+While a ``jax.profiler`` session records (``start_trace`` … ``stop_trace``),
+every live span — from a :class:`Tracer` or from :data:`NULL_TRACER` alike —
+is also a profiler ``TraceMe`` carrying its attributes as stats, so it lands
+in the ``.xplane.pb`` on the same clock as the device's operations, where a
+trace reader finds it (``ProfileData`` exposes the stats as
+``event.stats``). ``with span(...) as stats:`` hands the body a dict for
+stats known only at the end (``stats["blocked_us"] = ...``); a span nothing
+records hands out one that drops them. :data:`PROFILER` is the sink itself:
+``PROFILER.enabled`` is true while a session records, which is the guard a
+hot loop uses before it computes a span's stats. Retrospective
+:meth:`Tracer.event` rows and the Chrome export stay on the tracer's own
+clock. :func:`collection_spans` records each garbage collection as a
+``gc.collect`` span for a scope.
+
+Everything here imports nothing from ``repro``; apart from the standard
+library it imports only JAX's profiler binding, lazily, the first time it
+asks whether a session records (no JAX: nothing ever records).
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
+import gc
 import json
 import os
 import threading
@@ -56,10 +77,66 @@ __all__ = [
     "Tracer",
     "NullTracer",
     "NULL_TRACER",
+    "PROFILER",
+    "collection_spans",
     "current_tracer",
     "set_tracer",
     "use_tracer",
 ]
+
+
+@functools.cache
+def _trace_me() -> Any:
+    """JAX's profiler ``TraceMe`` type, or ``None`` where JAX is missing."""
+    try:
+        from jax._src.lib import _profiler
+    except ImportError:
+        return None
+    return _profiler.TraceMe
+
+
+class _ProfilerSink:
+    """The JAX profiler as a span sink: ``enabled`` while a profiler
+    session records (a call into the profiler, about 0.1 us)."""
+
+    @property
+    def enabled(self) -> bool:
+        trace_me = _trace_me()
+        return trace_me is not None and trace_me.is_enabled()
+
+
+PROFILER = _ProfilerSink()
+
+
+class _DroppedStats(dict):
+    """The stats dict of a span nothing records: writes are dropped."""
+
+    def __setitem__(self, key: str, value: Any) -> None:
+        return None
+
+    def update(self, *args: Any, **kwargs: Any) -> None:
+        return None
+
+
+class _ProfiledSpan:
+    """One live profiler ``TraceMe``; stats written into the dict that
+    ``__enter__`` returns are attached when the span closes."""
+
+    __slots__ = ("_me", "_stats")
+
+    def __init__(self, name: str, attrs: dict, stats: dict) -> None:
+        self._me = _trace_me()(name, **attrs)
+        self._stats = stats
+
+    def __enter__(self) -> dict:
+        self._me.__enter__()
+        return self._stats
+
+    def __exit__(self, *exc: Any) -> bool:
+        if self._stats:
+            self._me.set_metadata(**self._stats)
+        self._me.__exit__(*exc)
+        return False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,12 +217,22 @@ class Tracer:
         track: str = "engine",
         tid: int | str | None = None,
         **attrs: Any,
-    ) -> Iterator[None]:
+    ) -> Iterator[dict]:
         """Time a live code region; the event is recorded on exit (also on
-        exception — a failing stage still shows its time in the trace)."""
+        exception — a failing stage still shows its time in the trace).
+        Yields a dict whose entries join the attributes on exit; while a
+        profiler session records, the region is also a profiler span."""
+        stats: dict = {}
+        profiled = (
+            _ProfiledSpan(name, attrs, stats) if PROFILER.enabled else None
+        )
         t0 = time.perf_counter()
         try:
-            yield
+            if profiled is None:
+                yield stats
+            else:
+                with profiled:
+                    yield stats
         finally:
             t1 = time.perf_counter()
             self._append(
@@ -155,7 +242,7 @@ class Tracer:
                     dur_us=(t1 - t0) * 1e6,
                     track=track,
                     tid=tid if tid is not None else threading.get_ident(),
-                    args=attrs,
+                    args={**attrs, **stats},
                 )
             )
 
@@ -280,16 +367,27 @@ class Tracer:
 class NullTracer:
     """The disabled tracer: falsy, no-op spans, counter increments
     swallowed. One shared context manager instance, so the disabled
-    ``span()`` cost is a method call returning an existing object."""
+    ``span()`` cost is a method call and a profiler check returning an
+    existing object. While a profiler session records, ``span()`` still
+    reaches the profiler: ``enabled`` is about the in-process tracer only."""
 
     enabled = False
     counters = _NullCounters()
-    _span = contextlib.nullcontext()
+    _span = contextlib.nullcontext(_DroppedStats())
 
     def __bool__(self) -> bool:
         return False
 
-    def span(self, name: str, **attrs: Any) -> contextlib.nullcontext:
+    def span(
+        self,
+        name: str,
+        *,
+        track: str = "engine",
+        tid: int | str | None = None,
+        **attrs: Any,
+    ) -> contextlib.nullcontext | _ProfiledSpan:
+        if PROFILER.enabled:
+            return _ProfiledSpan(name, attrs, {})
         return self._span
 
     def event(self, name: str, **attrs: Any) -> None:
@@ -328,3 +426,29 @@ def use_tracer(tracer: Tracer | NullTracer | None) -> Iterator[None]:
         yield
     finally:
         _CURRENT = prev
+
+
+@contextlib.contextmanager
+def collection_spans() -> Iterator[None]:
+    """Record every garbage collection in the scope as a live
+    ``gc.collect`` span (stat ``generation``) on the ambient tracer, opened
+    and closed from ``gc.callbacks``: a collection holds the interpreter
+    lock, so it stalls every Python thread for its length."""
+    open_spans: list = []
+
+    def callback(phase: str, info: dict) -> None:
+        if phase == "start":
+            span = current_tracer().span(
+                "gc.collect", track="host runtime",
+                generation=info["generation"],
+            )
+            span.__enter__()
+            open_spans.append(span)
+        elif open_spans:
+            open_spans.pop().__exit__(None, None, None)
+
+    gc.callbacks.append(callback)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(callback)

@@ -37,12 +37,14 @@ trades exactly that wait against device efficiency.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from collections import deque
 from typing import Any, Callable, Mapping, Sequence
 
 import jax
 
+from repro.obs import PROFILER, collection_spans, current_tracer
 from repro.serve.lanes import Completion, LaneSet, lane_depth
 from repro.serve.loadgen import Request, Schedule
 
@@ -60,6 +62,10 @@ __all__ = [
 # enough not to burn a core spinning, short enough (100 us) to be noise
 # against the multi-ms latency budgets this path measures.
 _POLL_S = 1e-4
+# A sleep that returns later than this past what it asked for is marked
+# in the trace (``batcher.late_wake``): a host freeze, the interpreter
+# lock held elsewhere, or a host timer coarser than the poll.
+_LATE_WAKE_S = 1e-3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -297,12 +303,27 @@ def _coalescing_serve(
     """The shared batched/dynamic core: per-bucket FIFO queues, dispatch
     when a queue can fill its largest width or its oldest request has
     waited ``budget_s`` (or the stream ended — the flush), pad a partial
-    batch up to the smallest compiled width that holds it."""
-    queues: dict[str, deque[Request]] = {b: deque() for b in widths_by_bucket}
+    batch up to the smallest compiled width that holds it.
+
+    While a tracer or the JAX profiler records, ``batcher.dispatch`` spans
+    each batch's back-pressure and enqueue. Its stats split every member's
+    wait from its due time to ``t_dispatch`` three ways, summed over the
+    members: ``late_us``, due time to admission (the loop was elsewhere);
+    ``fill_us``, admission to the first pass at which the member could go
+    out (its queue held a full batch that included it, or was expired or
+    flushed); ``blocked_us``, that pass to ``t_dispatch`` (room under the
+    in-flight cap, and the batches dispatched before it). A sleep that
+    returns over 1 ms late is marked ``batcher.late_wake``, and each
+    garbage collection of the run is a ``gc.collect`` span."""
+    # A queued request is [request, admitted, dispatchable]: admitted is
+    # the pass's `now` that queued it; dispatchable is filled in only
+    # while tracing, by the first pass at which it could go out.
+    queues: dict[str, deque[list]] = {b: deque() for b in widths_by_bucket}
     inflight = _InflightBatches(concurrency)
     completions: list[Completion] = []
     batches: list[BatchExecution] = []
     requests = schedule.requests
+    tracer = current_tracer()
     i = 0
     t0 = time.perf_counter()
 
@@ -310,17 +331,14 @@ def _coalescing_serve(
         completions.extend(pairs[0])
         batches.extend(pairs[1])
 
-    def dispatch(bucket: str, cause: str) -> None:
-        widths = widths_by_bucket[bucket]
-        q = queues[bucket]
-        take = min(len(q), max(widths))
-        width = min(w for w in widths if w >= take)
-        members = [q.popleft() for _ in range(take)]
+    def enqueue(
+        members: list[Request], bucket: str, width: int, cause: str
+    ) -> float:
         # Retire old batches until this one fits the in-flight window. A
         # batch wider than the whole cap dispatches alone once the window
         # is empty (the cap bounds concurrency, it cannot shrink a batch).
         while inflight.inflight_requests and (
-            inflight.inflight_requests + take > inflight.cap
+            inflight.inflight_requests + len(members) > inflight.cap
         ):
             harvest(inflight.pop_oldest(t0))
         t_dispatch = time.perf_counter()
@@ -328,47 +346,102 @@ def _coalescing_serve(
             members, bucket, width, t_dispatch, cause,
             _call(calls, bucket, width),
         )
+        return t_dispatch
 
-    while i < len(requests) or any(queues.values()) or inflight.inflight_requests:
-        now = time.perf_counter()
-        while i < len(requests) and t0 + requests[i].arrival_s <= now:
-            req = requests[i]
-            if req.bucket not in queues:
-                raise KeyError(
-                    f"request {req.index} has bucket {req.bucket!r} with no "
-                    f"compiled executables; have {sorted(queues)}"
-                )
-            queues[req.bucket].append(req)
-            i += 1
-        harvest(inflight.poll(t0))
-        stream_done = i >= len(requests)
-        dispatched = False
-        for bucket, q in queues.items():
-            if not q:
-                continue
-            full = len(q) >= max(widths_by_bucket[bucket])
-            expired = now - (t0 + q[0].arrival_s) >= budget_s
-            if full or expired or stream_done:
+    def dispatch(bucket: str, cause: str, now: float) -> None:
+        widths = widths_by_bucket[bucket]
+        q = queues[bucket]
+        take = min(len(q), max(widths))
+        width = min(w for w in widths if w >= take)
+        queued = [q.popleft() for _ in range(take)]
+        members = [entry[0] for entry in queued]
+        if tracer.enabled or PROFILER.enabled:
+            due = [t0 + req.arrival_s for req in members]
+            # A member queued before tracing began has no mark: this pass.
+            ready = [now if rdy is None else rdy for _, _, rdy in queued]
+            with tracer.span(
+                "batcher.dispatch", track="serve loop", tid=f"queue {bucket}",
+                bucket=bucket, width=width, filled=take, cause=cause,
+                late_us=sum(e[1] - d for e, d in zip(queued, due)) * 1e6,
+                fill_us=sum(r - e[1] for e, r in zip(queued, ready)) * 1e6,
+            ) as stats:
+                t_dispatch = enqueue(members, bucket, width, cause)
+                stats["blocked_us"] = sum(t_dispatch - r for r in ready) * 1e6
+                stats["wait_us"] = sum(t_dispatch - d for d in due) * 1e6
+        else:
+            enqueue(members, bucket, width, cause)
+
+    with collection_spans():
+        while (
+            i < len(requests) or any(queues.values())
+            or inflight.inflight_requests
+        ):
+            now = time.perf_counter()
+            while i < len(requests) and t0 + requests[i].arrival_s <= now:
+                req = requests[i]
+                if req.bucket not in queues:
+                    raise KeyError(
+                        f"request {req.index} has bucket {req.bucket!r} with "
+                        f"no compiled executables; have {sorted(queues)}"
+                    )
+                queues[req.bucket].append([req, now, None])
+                i += 1
+            harvest(inflight.poll(t0))
+            stream_done = i >= len(requests)
+            dispatched = False
+            for bucket, q in queues.items():
+                if not q:
+                    continue
+                most = max(widths_by_bucket[bucket])
+                full = len(q) >= most
+                expired = now - (t0 + q[0][0].arrival_s) >= budget_s
+                if not (full or expired or stream_done):
+                    continue
+                if tracer.enabled or PROFILER.enabled:
+                    # Who could go out now: every full batch's members,
+                    # or the whole queue when it is one partial batch
+                    # (expired) or the stream ended (flush).
+                    n = len(q)
+                    if full and not stream_done:
+                        n -= n % most
+                    for entry in itertools.islice(q, n):
+                        if entry[2] is None:
+                            entry[2] = now
                 dispatch(
                     bucket,
                     "full" if full else ("expired" if expired else "flush"),
+                    now,
                 )
                 dispatched = True
-        if dispatched:
-            continue
-        # Nothing ready: sleep until the next arrival or the oldest
-        # queue deadline, in short slices so in-flight polls stay live.
-        next_arrival = (
-            t0 + requests[i].arrival_s if i < len(requests) else float("inf")
-        )
-        oldest = min(
-            (t0 + q[0].arrival_s + budget_s for q in queues.values() if q),
-            default=float("inf"),
-        )
-        wake = min(next_arrival, oldest)
-        delay = wake - time.perf_counter()
-        if delay > 0:
-            time.sleep(min(delay, _POLL_S) if inflight.inflight_requests else min(delay, 0.01))
+            if dispatched:
+                continue
+            # Nothing ready: sleep until the next arrival or the oldest
+            # queue deadline, in short slices so in-flight polls stay live.
+            next_arrival = (
+                t0 + requests[i].arrival_s if i < len(requests)
+                else float("inf")
+            )
+            oldest = min(
+                (t0 + q[0][0].arrival_s + budget_s
+                 for q in queues.values() if q),
+                default=float("inf"),
+            )
+            t_sleep = time.perf_counter()
+            delay = min(next_arrival, oldest) - t_sleep
+            if delay > 0:
+                asked = min(
+                    delay, _POLL_S if inflight.inflight_requests else 0.01
+                )
+                time.sleep(asked)
+                slept = time.perf_counter() - t_sleep
+                if slept - asked > _LATE_WAKE_S and (
+                    tracer.enabled or PROFILER.enabled
+                ):
+                    with tracer.span(
+                        "batcher.late_wake", track="serve loop",
+                        asked_us=asked * 1e6, slept_us=slept * 1e6,
+                    ):
+                        pass
     harvest(inflight.drain(t0))
     return BatchReport(tuple(completions), tuple(batches))
 

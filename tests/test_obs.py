@@ -7,6 +7,8 @@ medians is the least-contended sample), so a noisy CI neighbour cannot
 fail the build.
 """
 
+import gc
+import glob
 import inspect
 import json
 import subprocess
@@ -26,13 +28,17 @@ from repro.core.registry import BenchmarkSpec, Workload
 from repro.core.results import load_records, load_run
 from repro.obs import (
     NULL_TRACER,
+    PROFILER,
     Counters,
     NullTracer,
     Tracer,
+    collection_spans,
     current_tracer,
     use_tracer,
 )
+from repro.serve.batcher import serve_dynamic
 from repro.serve.client import run_closed_loop_threaded
+from repro.serve.loadgen import Request, Schedule
 
 FAST = dict(preset=0, iters=2, warmup=1)
 
@@ -138,6 +144,157 @@ def test_null_tracer_is_falsy_and_inert():
     NULL_TRACER.counters.set("n", 5)
     assert NULL_TRACER.events() == []
     assert NULL_TRACER.counters.snapshot() == {}
+
+
+def test_null_tracer_span_is_shared_noop_when_nothing_records():
+    assert not PROFILER.enabled
+    span = NULL_TRACER.span("batcher.dispatch", track="serve loop", width=4)
+    assert span is NULL_TRACER.span("other")
+    with span as stats:
+        stats["blocked_us"] = 1.0  # a span nothing records drops its stats
+        stats.update(wait_us=2.0)
+    assert dict(stats) == {}
+
+
+def test_tracer_span_stats_join_the_event():
+    tr = Tracer()
+    with tr.span("batcher.dispatch", track="serve loop", width=4) as stats:
+        stats["blocked_us"] = 12.5
+    (ev,) = tr.events()
+    assert ev.args == {"width": 4, "blocked_us": 12.5}
+    assert ev.track == "serve loop"
+
+
+def _profiled_host_events(trace_dir) -> dict:
+    import jax
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(path)
+    found: dict = {}
+    for plane in data.planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    found.setdefault(e.name, []).append(dict(e.stats))
+    return found
+
+
+@pytest.mark.parametrize("installed", [False, True], ids=["null", "tracer"])
+def test_spans_reach_the_profiler_with_their_stats(tmp_path, installed):
+    """While jax.profiler records, a span is a profiler event carrying its
+    attributes and its late stats, whether or not a Tracer is installed."""
+    import jax
+
+    tracer = Tracer() if installed else None
+    with use_tracer(tracer):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert PROFILER.enabled
+            with current_tracer().span(
+                "obs.test", track="serve loop", bucket="p3", width=4,
+            ) as stats:
+                stats["blocked_us"] = 7.25
+            with current_tracer().span("obs.marker", asked_us=100.0):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+    assert not PROFILER.enabled
+    found = _profiled_host_events(tmp_path)
+    assert found["obs.test"] == [{"bucket": "p3", "width": 4, "blocked_us": 7.25}]
+    assert found["obs.marker"] == [{"asked_us": 100.0}]
+    if installed:
+        assert [e.name for e in tracer.events()] == ["obs.test", "obs.marker"]
+        assert tracer.events()[0].args["blocked_us"] == 7.25
+
+
+def test_collection_spans_record_each_collection():
+    tr = Tracer()
+    with use_tracer(tr):
+        with collection_spans():
+            gc.collect()
+        gc.collect()  # outside the scope: not recorded
+    spans = [e for e in tr.events() if e.name == "gc.collect"]
+    assert [e.args["generation"] for e in spans] == [2]
+    assert spans[0].track == "host runtime" and spans[0].dur_us > 0
+
+
+class _Slow:
+    """A stand-in device result that is ready ``seconds`` after its call."""
+
+    def __init__(self, seconds):
+        self.t_ready = time.perf_counter() + seconds
+
+    def is_ready(self):
+        return time.perf_counter() >= self.t_ready
+
+    def block_until_ready(self):
+        time.sleep(max(0.0, self.t_ready - time.perf_counter()))
+        return self
+
+
+def _dispatch_spans(calls, requests, **kw):
+    tr = Tracer()
+    sched = Schedule(requests=tuple(requests), offered_qps=100.0)
+    with use_tracer(tr):
+        report = serve_dynamic(calls, sched, **kw)
+    return [e.args for e in tr.events() if e.name == "batcher.dispatch"], report
+
+
+def test_a_backlog_waits_as_blocked_not_as_fill():
+    """Nine requests at once and a straggler: the first pass finds two
+    full batches, so members 5-8 are dispatchable at once and their wait
+    for room under the cap is blocked time, not fill; member 9 waits to
+    fill until the straggler's arrival flushes the stream."""
+    calls = {"a": {w: (lambda: _Slow(0.02)) for w in (1, 2, 4)}}
+    reqs = [Request(index=i, arrival_s=0.0, bucket="a") for i in range(9)]
+    reqs.append(Request(index=9, arrival_s=0.3, bucket="a"))
+    spans, _ = _dispatch_spans(calls, reqs, budget_s=10.0, concurrency=4)
+    assert [(a["filled"], a["cause"]) for a in spans] == [(4, "full"), (4, "full"), (2, "flush")]
+    first, second, last = spans
+    assert first["fill_us"] == second["fill_us"] == 0.0
+    assert second["blocked_us"] >= 4 * 0.015e6  # the first batch's 20 ms
+    assert last["fill_us"] >= 0.25e6  # member 9, for the straggler
+
+
+def test_batcher_dispatch_spans_split_each_wait():
+    """For every batch, late + fill + blocked over its members equals the
+    members' summed wait from due time to t_dispatch, as the report's own
+    timestamps give it; a slow call makes arrivals late and a cap of 2
+    in flight makes batches wait for room."""
+
+    def call():
+        time.sleep(0.001)
+        return _Slow(0.004)
+
+    calls = {b: {w: call for w in (1, 2, 4)} for b in "ab"}
+    rng = np.random.default_rng(3)
+    arrivals = np.cumsum(rng.exponential(0.0015, size=60))
+    spans, report = _dispatch_spans(
+        calls,
+        [
+            Request(index=i, arrival_s=float(t), bucket="b" if i % 3 == 0 else "a")
+            for i, t in enumerate(arrivals)
+        ],
+        budget_s=0.002, concurrency=2,
+    )
+    assert len(spans) == len(report.batches)
+    blocked = 0.0
+    for a, batch in zip(spans, report.batches):
+        assert (a["bucket"], a["width"], a["filled"], a["cause"]) == (
+            batch.bucket, batch.width, batch.filled, batch.cause,
+        )
+        members = [
+            c for c in report.completions
+            if (c.bucket, c.t_done) == (batch.bucket, batch.t_done)
+        ]
+        assert len(members) == batch.filled
+        waited = sum(batch.t_dispatch - c.t_submit for c in members) * 1e6
+        split = a["late_us"] + a["fill_us"] + a["blocked_us"]
+        assert split == pytest.approx(waited, rel=1e-6, abs=1e-3)
+        assert a["wait_us"] == pytest.approx(waited, rel=1e-6, abs=1e-3)
+        assert min(a["late_us"], a["fill_us"], a["blocked_us"]) >= 0.0
+        blocked += a["blocked_us"]
+    assert blocked > 0  # the cap of 2 in flight held some batch back
 
 
 # -- Chrome export -----------------------------------------------------------
