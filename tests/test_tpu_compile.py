@@ -80,6 +80,18 @@ def test_declared_kernel_compiles_for_v5e(name, one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# The GEMM benchmark's size: blocks picked for 8192² must stay inside the
+# chip's scoped VMEM, which the CPU host sees only through this compile.
+@pytest.mark.parametrize("name", ["gemm_bf16_nn", "gemm_f32_nn"])
+def test_gemm_compiles_for_v5e_at_n8192(name, one_chip, monkeypatch):
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    workload = get_benchmark(name).build_preset(PRESET, n=8192)
+    shapes = jax.eval_shape(lambda: workload.make_inputs(0))
+    with ops.force_impl("pallas", workload.pallas_kernel):
+        compiled = _compile(workload.fn, shapes, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_flash_attention_compiles_for_v5e(one_chip):
     from repro.kernels.flash_attention import flash_attention_pallas
 
