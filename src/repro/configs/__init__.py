@@ -23,6 +23,7 @@ ARCHS = (
     "hubert-xlarge",
     "jamba-1.5-large-398b",
     "qwen2-vl-2b",
+    "moonlight-16b-a3b",
 )
 
 _MODULES = {name: "repro.configs." + name.replace("-", "_").replace(".", "_") for name in ARCHS}

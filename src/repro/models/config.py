@@ -6,7 +6,8 @@ The layer stack is expressed as a repeating *period* of block kinds
 (``block_period``), which is also the scan unit (DESIGN.md §5): dense models
 have period ``("attn", "mlp")``-fused blocks; jamba has a period of 8 mixed
 mamba/attention layers with MoE on alternating layers; xLSTM alternates
-mLSTM/sLSTM blocks.
+mLSTM/sLSTM blocks. Leading dense layers (``first_dense``, DeepSeek-V3's
+``first_k_dense_replace``) sit before the period scan and are not part of it.
 """
 
 from __future__ import annotations
@@ -43,12 +44,35 @@ class ArchConfig:
     causal: bool = True  # False => bidirectional encoder
     rope: Literal["rope", "mrope", "none"] = "rope"
     rope_theta: float = 1e4
+    # Multi-head latent attention (DeepSeek-V2/V3, ``attention="mla"``): keys
+    # and values come from a rank-``kv_lora_rank`` latent; each head's query
+    # and key are [nope (head_dim) | rope (qk_rope_dim)], its value
+    # ``v_head_dim`` wide. One key rope part is shared by all heads, and the
+    # decode cache holds the latent and that rope part, not K and V.
+    attention: Literal["gqa", "mla"] = "gqa"
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     mrope_sections: tuple[int, int, int] = (16, 24, 24)  # t/h/w pairs (half-dim)
     # MoE
     n_experts: int = 0
     top_k: int = 0
     moe_every: int = 1  # MoE FFN on layers where (layer % moe_every == moe_offset)
     moe_offset: int = 0
+    # Leading dense layers before the MoE stack, with their own SwiGLU width.
+    first_dense: int = 0
+    dense_d_ff: int = 0
+    # DeepSeek-V3 MoE: ``n_shared_experts`` SwiGLUs of width d_ff every token
+    # passes through; ``router="sigmoid"`` scores experts with a sigmoid,
+    # selects the top_k of score + a correction bias, and weights them by
+    # score over the selected scores' sum, times ``routed_scale``.
+    n_shared_experts: int = 0
+    router: Literal["softmax", "sigmoid"] = "softmax"
+    routed_scale: float = 1.0
+    # The routed experts [first, stop) this chip holds (expert parallelism):
+    # the router scores all n_experts, the layer computes only these, without
+    # capacity drops. None: all of them (the "sigmoid" router's layer only).
+    held_experts: tuple[int, int] | None = None
     capacity_factor: float = 1.25
     moe_group_size: int = 1024
     # Expert slicing (§Perf mixtral iteration): split each expert's SwiGLU
@@ -74,7 +98,7 @@ class ArchConfig:
     xlstm_chunk: int = 0
     # Performance knobs (beyond-paper optimizations; EXPERIMENTS.md §Perf)
     attn_chunk: int = 0  # >0: chunked online-softmax attention (KV blocks)
-    score_dtype: str = "float32"  # attention score matmul accumulation dtype
+    score_dtype: str = "float32"  # attention matmul operand dtype (fp32 accumulation)
     unroll_inner: bool = False  # unroll inner chunk scans (cost-analysis mode)
     # I/O
     input_mode: Literal["tokens", "embeds"] = "tokens"
@@ -94,13 +118,24 @@ class ArchConfig:
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
+    @property
+    def held(self) -> tuple[int, int]:
+        return self.held_experts or (0, self.n_experts)
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of a query/key head: head_dim, plus the rope part under MLA."""
+        return self.head_dim + (self.qk_rope_dim if self.attention == "mla" else 0)
+
     # ---- layer stack -----------------------------------------------------
     def block_kinds(self) -> tuple[BlockKind, ...]:
         """Kind of every layer, length n_layers."""
         kinds: list[BlockKind] = []
         for i in range(self.n_layers):
             moe = self.n_experts > 0 and (i % self.moe_every == self.moe_offset)
-            if self.family == "ssm":
+            if i < self.first_dense:
+                kinds.append("attn_mlp")
+            elif self.family == "ssm":
                 kinds.append("mlstm" if i % 2 == 0 else "slstm")
             elif self.attn_period > 0:  # hybrid
                 if i % self.attn_period == self.attn_offset:
@@ -112,8 +147,9 @@ class ArchConfig:
         return tuple(kinds)
 
     def block_period(self) -> tuple[BlockKind, ...]:
-        """Smallest repeating unit of the stack (the scan body)."""
-        kinds = self.block_kinds()
+        """Smallest repeating unit of the stack after the leading dense
+        layers (the scan body)."""
+        kinds = self.block_kinds()[self.first_dense :]
         for p in range(1, len(kinds) + 1):
             if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p):
                 return kinds[:p]
@@ -121,7 +157,7 @@ class ArchConfig:
 
     @property
     def n_periods(self) -> int:
-        return self.n_layers // len(self.block_period())
+        return (self.n_layers - self.first_dense) // len(self.block_period())
 
     # ---- parameter counting (for MODEL_FLOPS = 6·N·D) ---------------------
     def param_counts(self) -> dict[str, float]:
@@ -130,9 +166,20 @@ class ArchConfig:
         attn = d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + self.n_heads * hd * d
         if self.qkv_bias:
             attn += (self.n_heads + 2 * self.n_kv_heads) * hd
+        if self.attention == "mla":
+            r, H = self.kv_lora_rank, self.n_heads
+            attn = (
+                d * H * self.qk_head_dim  # q_proj
+                + d * (r + self.qk_rope_dim) + r  # kv_a_proj_with_mqa, its norm
+                + r * H * (hd + self.v_head_dim)  # kv_b_proj
+                + H * self.v_head_dim * d  # o_proj
+            )
         mlp = 3 * d * ff
-        moe_total = self.n_experts * mlp + d * self.n_experts
-        moe_active = self.top_k * mlp + d * self.n_experts
+        first, stop = self.held
+        shared = self.n_shared_experts * mlp
+        router = d * self.n_experts + (self.n_experts if self.router == "sigmoid" else 0)
+        moe_total = (stop - first) * mlp + shared + router
+        moe_active = self.top_k * mlp + shared + router
         di, ds, dtr = self.d_inner, self.ssm_state, self.dt_rank
         mamba = (
             d * 2 * di  # in_proj
@@ -147,7 +194,8 @@ class ArchConfig:
         slstm = d * 4 * d + self.xlstm_heads * dh * dh * 4 + d * (4 * d // 3) * 2
         total = 0.0
         active = 0.0
-        for kind in self.block_kinds():
+        dense_mlp = 3 * d * self.dense_d_ff
+        for i, kind in enumerate(self.block_kinds()):
             if kind.startswith("attn"):
                 total += attn
                 active += attn
@@ -158,8 +206,8 @@ class ArchConfig:
                 total += moe_total
                 active += moe_active
             elif kind.endswith("_mlp"):
-                total += mlp
-                active += mlp
+                total += dense_mlp if i < self.first_dense else mlp
+                active += dense_mlp if i < self.first_dense else mlp
             if kind == "mlstm":
                 total += mlstm
                 active += mlstm
@@ -180,4 +228,10 @@ class ArchConfig:
             assert 0 < self.top_k <= self.n_experts
         if self.family == "ssm":
             assert self.n_layers % 2 == 0, "xLSTM alternates mLSTM/sLSTM pairs"
-        assert self.n_layers % len(self.block_period()) == 0
+        assert (self.n_layers - self.first_dense) % len(self.block_period()) == 0
+        if self.attention == "mla":
+            assert self.block_period() == ("attn_moe",), "MLA stacks are DeepSeek MoE stacks"
+            assert self.router == "sigmoid" and self.kv_lora_rank and self.qk_rope_dim
+        if self.router == "sigmoid":
+            first, stop = self.held
+            assert 0 <= first < stop <= self.n_experts, self.held_experts

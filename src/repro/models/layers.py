@@ -50,7 +50,9 @@ def rms_norm(x: jax.Array, gamma: jax.Array, eps: float) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def rope_angles(cfg: ArchConfig, positions: jax.Array) -> tuple[jax.Array, jax.Array]:
+def rope_angles(
+    cfg: ArchConfig, positions: jax.Array, dim: int | None = None
+) -> tuple[jax.Array, jax.Array]:
     """cos/sin tables for (possibly multimodal) positions.
 
     ``positions``: (B, T) int for plain RoPE, or (B, T, 3) for M-RoPE where
@@ -58,9 +60,10 @@ def rope_angles(cfg: ArchConfig, positions: jax.Array) -> tuple[jax.Array, jax.A
     assigns each rotary frequency pair to one of the three sections
     (Qwen2-VL §3.1); for text, all three ids are equal, making M-RoPE
     degenerate to RoPE — checked in tests.
-    Returns cos/sin of shape (B, T, head_dim/2), fp32.
+    ``dim`` is the width rotated (default head_dim; MLA rotates only its
+    rope part). Returns cos/sin of shape (B, T, dim/2), fp32.
     """
-    half = cfg.head_dim // 2
+    half = (dim or cfg.head_dim) // 2
     freqs = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     if positions.ndim == 2:
         pos = positions[..., None].astype(jnp.float32)  # (B, T, 1)
@@ -142,7 +145,7 @@ def project_qkv(p: dict, cfg: ArchConfig, x: jax.Array, positions: jax.Array):
 def sdpa(
     q: jax.Array,  # (B, T, H, hd)
     k: jax.Array,  # (B, S, KV, hd)
-    v: jax.Array,  # (B, S, KV, hd)
+    v: jax.Array,  # (B, S, KV, hv); hv may differ from hd (MLA)
     *,
     causal: bool,
     window: int | None,
@@ -159,11 +162,12 @@ def sdpa(
       flash-attention recurrence in pure XLA. Never materializes the (T, S)
       score matrix; the per-step working set is (T, chunk). This is the
       memory-term optimization that brings 32k prefill under the HBM budget.
-    - ``score_dtype``: accumulation dtype of the QKᵀ matmul (bf16 halves
-      score-buffer traffic on the dense path at ~1e-2 logit error).
+    - ``score_dtype``: operand dtype of the QKᵀ and PV matmuls, which
+      accumulate in fp32 (bf16 halves the operand traffic).
     """
     B, T, H, hd = q.shape
-    S, KV = k.shape[1], k.shape[2]
+    S, KV, hv = k.shape[1], k.shape[2], v.shape[-1]
+    f32 = jnp.float32
     group = H // KV
     scale = hd**-0.5
     qf = (q.astype(jnp.float32) * scale).astype(score_dtype).reshape(B, T, KV, group, hd)
@@ -182,12 +186,12 @@ def sdpa(
     if chunk and S % chunk == 0 and S > chunk:
         n_chunks = S // chunk
         kc = k.astype(score_dtype).reshape(B, n_chunks, chunk, KV, hd)
-        vc = v.astype(jnp.float32).reshape(B, n_chunks, chunk, KV, hd)
+        vc = v.astype(score_dtype).reshape(B, n_chunks, chunk, KV, hv)
 
         def body(carry, inp):
             m_run, l_run, acc = carry
             kj, vj, j = inp
-            s = jnp.einsum("btkgh,bskh->bkgts", qf, kj).astype(jnp.float32)
+            s = jnp.einsum("btkgh,bskh->bkgts", qf, kj, preferred_element_type=f32)
             k_pos = j * chunk + jnp.arange(chunk)[None, :]
             m = mask_for(k_pos)
             s = jnp.where(m[None, None, None], s, -1e30)
@@ -195,13 +199,14 @@ def sdpa(
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m_run - m_new)
             l_new = l_run * corr + jnp.sum(p, axis=-1)
-            acc = acc * corr[..., None] + jnp.einsum("bkgts,bskh->bkgth", p, vj)
+            pv = jnp.einsum("bkgts,bskh->bkgth", p.astype(vj.dtype), vj, preferred_element_type=f32)
+            acc = acc * corr[..., None] + pv
             return (m_new, l_new, acc), None
 
         init = (
-            jnp.full((B, KV, group, T), -1e30, jnp.float32),
-            jnp.zeros((B, KV, group, T), jnp.float32),
-            jnp.zeros((B, KV, group, T, hd), jnp.float32),
+            jnp.full((B, KV, group, T), -1e30, f32),
+            jnp.zeros((B, KV, group, T), f32),
+            jnp.zeros((B, KV, group, T, hv), f32),
         )
         ks = jnp.swapaxes(kc, 0, 1)  # (n_chunks, B, chunk, KV, hd)
         vs = jnp.swapaxes(vc, 0, 1)
@@ -209,16 +214,17 @@ def sdpa(
             body, init, (ks, vs, jnp.arange(n_chunks)), unroll=unroll_inner
         )
         out = acc / jnp.maximum(l_run, 1e-30)[..., None]
-        out = jnp.moveaxis(out, -2, 1)  # (B, T, KV, group, hd)
-        return out.reshape(B, T, H, hd).astype(q.dtype)
+        out = jnp.moveaxis(out, -2, 1)  # (B, T, KV, group, hv)
+        return out.reshape(B, T, H, hv).astype(q.dtype)
 
     kf = k.astype(score_dtype)
-    s = jnp.einsum("btkgh,bskh->bkgts", qf, kf).astype(jnp.float32)
+    s = jnp.einsum("btkgh,bskh->bkgts", qf, kf, preferred_element_type=f32)
     k_pos = jnp.arange(S)[None, :]
     s = jnp.where(mask_for(k_pos)[None, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgts,bskh->btkgh", p, v.astype(jnp.float32))
-    return out.reshape(B, T, H, hd).astype(q.dtype)
+    vf = v.astype(score_dtype)
+    out = jnp.einsum("bkgts,bskh->btkgh", p.astype(vf.dtype), vf, preferred_element_type=f32)
+    return out.reshape(B, T, H, hv).astype(q.dtype)
 
 
 def apply_attention(

@@ -16,6 +16,13 @@ Structure (DESIGN.md §5):
   function applying ``with_sharding_constraint`` to the residual stream
   (batch over data axes; sequence over model for SP) without the model
   depending on any mesh.
+- DeepSeek-V3 stacks (``attention="mla"``) take the checkpoint's own
+  tensors as their parameters: a flat dict under the checkpoint's names,
+  [in, out] oriented, in which the leading dense layers keep their own
+  names (``model.layers.0.``) and the MoE layers' tensors are stacked on a
+  leading axis under ``model.layers.*.`` (the held experts on a second one,
+  ``mlp.experts.*.``). The dense layers run before the scan over that axis;
+  the cache is each layer's MLA latent under the same prefixes.
 """
 
 from __future__ import annotations
@@ -25,7 +32,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.models import ssm
+from repro.models import mla, ssm
 from repro.models.config import ArchConfig
 from repro.models.layers import (
     apply_attention,
@@ -36,7 +43,16 @@ from repro.models.layers import (
     init_mlp,
     rms_norm,
 )
-from repro.models.moe import apply_moe, init_moe
+from repro.models.moe import (
+    BIAS,
+    EXPERTS,
+    ROUTER,
+    SHARED,
+    apply_moe,
+    apply_moe_dropless,
+    init_moe,
+    swiglu,
+)
 
 __all__ = ["Model"]
 
@@ -218,6 +234,114 @@ def _apply_block_step(
 
 
 # ---------------------------------------------------------------------------
+# DeepSeek-V3 stacks: MLA + leading dense layers + dropless MoE, on the
+# checkpoint's own tensors.
+# ---------------------------------------------------------------------------
+
+EMBED = "model.embed_tokens.weight"
+NORM = "model.norm.weight"
+HEAD = "lm_head.weight"
+LN1 = "input_layernorm.weight"
+LN2 = "post_attention_layernorm.weight"
+DENSE = "mlp.{}_proj.weight"
+STACK = "model.layers.*."
+
+
+def dense_prefix(i: int) -> str:
+    return f"model.layers.{i}."
+
+
+def ckpt_layout(cfg: ArchConfig) -> dict[str, tuple[tuple[int, ...], Any, float | str]]:
+    """Every tensor of a DeepSeek-V3 stack: name -> (shape, dtype, init),
+    init being a normal's scale, ``"ones"`` (norms) or ``"zeros"``."""
+    dt, f32 = dtype_of(cfg), jnp.float32
+    d, H, n, r = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.kv_lora_rank
+    e, v, f, E = cfg.qk_rope_dim, cfg.v_head_dim, cfg.d_ff, cfg.n_experts
+    out = 0.02 / (2 * cfg.n_layers) ** 0.5
+    first, stop = cfg.held
+
+    def attn(lead):
+        return {
+            LN1: (lead + (d,), dt, "ones"),
+            mla.Q: (lead + (d, H * (n + e)), dt, 0.02),
+            mla.KV_A: (lead + (d, r + e), dt, 0.02),
+            mla.KV_NORM: (lead + (r,), dt, "ones"),
+            mla.KV_B: (lead + (r, H * (n + v)), dt, 0.02),
+            mla.O: (lead + (H * v, d), dt, out),
+            LN2: (lead + (d,), dt, "ones"),
+        }
+
+    def mlp(name, lead, width):
+        return {
+            name.format("gate"): (lead + (d, width), dt, 0.02),
+            name.format("up"): (lead + (d, width), dt, 0.02),
+            name.format("down"): (lead + (width, d), dt, out),
+        }
+
+    layout = {EMBED: ((cfg.vocab, d), dt, 0.02)}
+    for i in range(cfg.first_dense):
+        layer = attn(()) | mlp(DENSE, (), cfg.dense_d_ff)
+        layout |= {dense_prefix(i) + k: s for k, s in layer.items()}
+    L = (cfg.n_periods,)
+    layer = (
+        attn(L)
+        | {ROUTER: (L + (d, E), f32, 0.02), BIAS: (L + (E,), f32, "zeros")}
+        | mlp(EXPERTS, L + (stop - first,), f)
+        | mlp(SHARED, L, cfg.n_shared_experts * f)
+    )
+    layout |= {STACK + k: s for k, s in layer.items()}
+    layout |= {NORM: ((d,), dt, "ones"), HEAD: ((d, cfg.vocab), dt, 0.02)}
+    return layout
+
+
+def _init_ckpt(key, cfg: ArchConfig) -> dict:
+    params = {}
+    for i, (name, (shape, dt, init)) in enumerate(ckpt_layout(cfg).items()):
+        if init == "ones":
+            params[name] = jnp.ones(shape, dt)
+        elif init == "zeros":
+            params[name] = jnp.zeros(shape, dt)
+        else:
+            w = jax.random.truncated_normal(jax.random.fold_in(key, i), -2, 2, shape)
+            params[name] = (init * w).astype(dt)
+    return params
+
+
+def _layer(params: dict, prefix: str) -> dict:
+    """One layer's tensors (or the stack's), keyed without ``prefix``."""
+    return {k[len(prefix):]: w for k, w in params.items() if k.startswith(prefix)}
+
+
+def _ckpt_ffn(p, cfg: ArchConfig, x: jax.Array, moe: bool):
+    """The layer's FFN on the normed residual (N, d) -> (out, held-expert
+    token counts or None)."""
+    xn = rms_norm(x, p[LN2], cfg.norm_eps)
+    if moe:
+        with jax.named_scope("moe"):
+            return apply_moe_dropless(p, cfg, xn)
+    return swiglu(xn, *(p[DENSE.format(w)] for w in ("gate", "up", "down"))), None
+
+
+def _ckpt_block(p, cfg: ArchConfig, x, positions, shard: ShardFn, moe: bool):
+    """One full-sequence layer: x (B, T, d) -> (x, latent, held-expert
+    token counts or None)."""
+    with jax.named_scope("mla"):
+        h, latent = mla.attend(p, cfg, rms_norm(x, p[LN1], cfg.norm_eps), positions)
+    x = shard(x + h, "residual")
+    B, T, d = x.shape
+    ffn, counts = _ckpt_ffn(p, cfg, x.reshape(B * T, d), moe)
+    return shard(x + ffn.reshape(B, T, d), "residual"), latent, counts
+
+
+def _ckpt_block_step(p, cfg: ArchConfig, x_t, cache, pos, moe: bool):
+    """One decode step of one layer: x_t (B, d) -> (x_t, cache)."""
+    with jax.named_scope("mla"):
+        h, cache = mla.attend_step(p, cfg, rms_norm(x_t, p[LN1], cfg.norm_eps), cache, pos)
+    x_t = x_t + h
+    return x_t + _ckpt_ffn(p, cfg, x_t, moe)[0], cache
+
+
+# ---------------------------------------------------------------------------
 # The model.
 # ---------------------------------------------------------------------------
 
@@ -244,6 +368,8 @@ class Model:
     # ---- parameters -------------------------------------------------------
     def init(self, key) -> dict:
         cfg = self.cfg
+        if cfg.attention == "mla":
+            return _init_ckpt(key, cfg)
         dt = dtype_of(cfg)
         n_posns = len(self.period)
         keys = jax.random.split(key, cfg.n_layers + 3)
@@ -286,9 +412,53 @@ class Model:
         logits = x.astype(jnp.float32) @ w.astype(jnp.float32)
         return self.shard(logits, "logits")
 
+    # ---- DeepSeek-V3 stacks -------------------------------------------------
+    def _ckpt_prefill(self, params, batch, max_len: int, remat: bool = False):
+        """-> (final-normed x (B, T, d), cache, held-expert token counts
+        (MoE layers, held))."""
+        cfg = self.cfg
+        tokens = batch["tokens"]
+        B, T = tokens.shape
+        assert T <= max_len, (T, max_len)
+        x = self.shard(jnp.take(params[EMBED], tokens, axis=0), "embed")
+        positions = batch.get("positions", jnp.broadcast_to(jnp.arange(T)[None], (B, T)))
+
+        def pad(latent):
+            return jnp.pad(latent, ((0, 0), (0, max_len - T), (0, 0)))
+
+        cache = {}
+        for i in range(cfg.first_dense):
+            x, latent, _ = _ckpt_block(
+                _layer(params, dense_prefix(i)), cfg, x, positions, self.shard, moe=False
+            )
+            cache[dense_prefix(i)] = pad(latent)
+
+        def body(x, p):
+            x, latent, counts = _ckpt_block(p, cfg, x, positions, self.shard, moe=True)
+            return x, (pad(latent), counts)
+
+        body = jax.checkpoint(body) if remat else body
+        x, (cache[STACK], counts) = jax.lax.scan(
+            body, x, _layer(params, STACK), unroll=self.scan_unroll
+        )
+        return rms_norm(x, params[NORM], cfg.norm_eps), cache, counts
+
+    def _ckpt_logits(self, params, x: jax.Array) -> jax.Array:
+        return self.shard(mla.mm(x, params[HEAD]).astype(jnp.float32), "logits")
+
+    def prefill_last(self, params, batch, max_len: int):
+        """The serving prefill of a DeepSeek-V3 stack: returns (logits of the
+        last position (B, V), cache, tokens routed to each held expert of
+        each MoE layer (n_periods, held))."""
+        x, cache, counts = self._ckpt_prefill(params, batch, max_len)
+        return self._ckpt_logits(params, x[:, -1]), cache, counts
+
     # ---- training / encoder forward ----------------------------------------
     def forward(self, params, batch) -> jax.Array:
         cfg = self.cfg
+        if cfg.attention == "mla":
+            x, _, _ = self._ckpt_prefill(params, batch, batch["tokens"].shape[1], self.remat)
+            return self._ckpt_logits(params, x)
         x, positions = self._embed_in(params, batch)
         T = x.shape[1]
 
@@ -317,6 +487,11 @@ class Model:
     # ---- serving ------------------------------------------------------------
     def init_cache(self, batch: int, max_len: int):
         cfg = self.cfg
+        if cfg.attention == "mla":
+            width, dt = (batch, max_len, cfg.kv_lora_rank + cfg.qk_rope_dim), dtype_of(cfg)
+            cache = {dense_prefix(i): jnp.zeros(width, dt) for i in range(cfg.first_dense)}
+            cache[STACK] = jnp.zeros((cfg.n_periods,) + width, dt)
+            return cache
         one_period = tuple(
             _init_block_cache(kind, cfg, batch, max_len) for kind in self.period
         )
@@ -328,6 +503,9 @@ class Model:
     def prefill(self, params, batch, max_len: int):
         """Run the prompt; returns (cache, logits (B, T, V))."""
         cfg = self.cfg
+        if cfg.attention == "mla":
+            x, cache, _ = self._ckpt_prefill(params, batch, max_len)
+            return cache, self._ckpt_logits(params, x)
         x, positions = self._embed_in(params, batch)
         T = x.shape[1]
 
@@ -350,6 +528,8 @@ class Model:
         """One token step. tokens (B,) int32, pos scalar absolute position.
         Returns (logits (B, V), new cache)."""
         cfg = self.cfg
+        if cfg.attention == "mla":
+            return self._ckpt_decode_step(params, cache, tokens, pos)
         x_t = jnp.take(params["embed"], tokens, axis=0)  # (B, d)
         B = x_t.shape[0]
         positions_t = jnp.broadcast_to(pos[None, None], (B, 1))
@@ -372,3 +552,22 @@ class Model:
         x_t = rms_norm(x_t, params["ln_f"], cfg.norm_eps)
         logits = self._unembed(params, x_t)
         return logits, new_cache
+
+    def _ckpt_decode_step(self, params, cache, tokens, pos):
+        cfg = self.cfg
+        x_t = jnp.take(params[EMBED], tokens, axis=0)
+        new = {}
+        for i in range(cfg.first_dense):
+            prefix = dense_prefix(i)
+            x_t, new[prefix] = _ckpt_block_step(
+                _layer(params, prefix), cfg, x_t, cache[prefix], pos, moe=False
+            )
+
+        def body(x_t, inp):
+            p, layer_cache = inp
+            return _ckpt_block_step(p, cfg, x_t, layer_cache, pos, moe=True)
+
+        x_t, new[STACK] = jax.lax.scan(
+            body, x_t, (_layer(params, STACK), cache[STACK]), unroll=self.scan_unroll
+        )
+        return self._ckpt_logits(params, rms_norm(x_t, params[NORM], cfg.norm_eps)), new

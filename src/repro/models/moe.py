@@ -12,6 +12,15 @@ mixtral-scale FFNs — quantified in EXPERIMENTS.md §Roofline).
 Top-k routing with softmax-renormalized weights over the selected experts
 (Mixtral's scheme); tokens over capacity are dropped (standard GShard
 behaviour — tests use full capacity so the oracle comparison is exact).
+
+``apply_moe_dropless`` is DeepSeek-V3's layer (``router="sigmoid"``), as
+one chip of an expert-parallel deployment runs it: the router scores all
+``n_experts``, the layer computes only the experts it holds
+(``held_experts``), with every token routed to them and none dropped, plus
+the shared experts every token passes through. Assignments are sorted by
+held expert and the experts run as one grouped matmul
+(``jax.lax.ragged_dot``). What the experts held elsewhere add is left out.
+Its weights are the checkpoint's own tensors (``mlp.gate.weight``, ...).
 """
 
 from __future__ import annotations
@@ -19,10 +28,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
 from repro.models.config import ArchConfig
 from repro.models.layers import dtype_of, init_dense
 
-__all__ = ["init_moe", "apply_moe", "moe_oracle"]
+__all__ = ["init_moe", "apply_moe", "moe_oracle", "route_sigmoid", "apply_moe_dropless", "swiglu"]
+
+ROUTER = "mlp.gate.weight"
+BIAS = "mlp.gate.e_score_correction_bias"
+EXPERTS = "mlp.experts.*.{}_proj.weight"
+SHARED = "mlp.shared_experts.{}_proj.weight"
 
 
 def init_moe(key, cfg: ArchConfig) -> dict:
@@ -131,3 +146,58 @@ def moe_oracle(p: dict, cfg: ArchConfig, x: jax.Array) -> jax.Array:
         outs.append(h @ p["w_down"][e])
     y = sum(w[:, e : e + 1].astype(x.dtype) * outs[e] for e in range(cfg.n_experts))
     return y.reshape(B, T, d)
+
+
+def route_sigmoid(logits: jax.Array, bias: jax.Array, cfg: ArchConfig):
+    """DeepSeek-V3's router on f32 logits (N, E): s = sigmoid(logits); the
+    top_k of s + bias are selected; each weighs s over the selected s's sum,
+    times ``routed_scale``. The bias selects but does not weight. Returns
+    (expert ids (N, k), weights (N, k))."""
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + bias, cfg.top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, w / jnp.sum(w, axis=-1, keepdims=True) * cfg.routed_scale
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """x (N, d) through a SwiGLU, each product through the kernel layer."""
+    return ops.matmul(jax.nn.silu(ops.matmul(x, w_gate)) * ops.matmul(x, w_up), w_down)
+
+
+def apply_moe_dropless(p: dict, cfg: ArchConfig, x: jax.Array):
+    """x (N, d) -> (y (N, d), tokens routed to each held expert (held,)).
+
+    ``p`` holds one layer's tensors: the router (d, E) and its correction
+    bias (E,) in f32, the held experts' SwiGLUs stacked (held, d, f) /
+    (held, f, d), and the shared experts as one SwiGLU of width
+    n_shared_experts·f."""
+    N, d = x.shape
+    k = cfg.top_k
+    first, stop = cfg.held
+    held = stop - first
+    with jax.named_scope("route"):
+        logits = jnp.dot(
+            x.astype(jnp.float32), p[ROUTER], precision=jax.lax.Precision.HIGHEST
+        )
+        idx, w = route_sigmoid(logits, p[BIAS], cfg)
+        local = idx - first
+        mine = (local >= 0) & (local < held)
+        # Each assignment's held expert, or ``held`` for those held elsewhere,
+        # which sort last and fall outside every group.
+        group = jnp.where(mine, local, held).reshape(-1)
+        order = jnp.argsort(group, stable=True)
+        counts = jnp.sum(group[:, None] == jnp.arange(held), axis=0, dtype=jnp.int32)
+    with jax.named_scope("experts"):
+        xs = x[order // k]
+        gate = jax.lax.ragged_dot(xs, p[EXPERTS.format("gate")], counts)
+        up = jax.lax.ragged_dot(xs, p[EXPERTS.format("up")], counts)
+        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p[EXPERTS.format("down")], counts)
+    with jax.named_scope("combine"):
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k, dtype=order.dtype))
+        # Rows past the held groups are no expert's output: select, not
+        # multiply, so whatever they hold cannot leak in.
+        per_slot = jnp.where(mine[..., None], ys[back].reshape(N, k, d), 0)
+        y = jnp.sum(per_slot.astype(jnp.float32) * w[..., None], axis=1)
+    with jax.named_scope("shared"):
+        shared = swiglu(x, *(p[SHARED.format(n)] for n in ("gate", "up", "down")))
+    return (y + shared.astype(jnp.float32)).astype(x.dtype), counts

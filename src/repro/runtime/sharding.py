@@ -63,6 +63,20 @@ _PARAM_RULES: dict[str, tuple[int, ...]] = {
     "ln": (), "ln1": (), "ln2": (), "ln_f": (),
 }
 
+# DeepSeek-V3 stacks keep the checkpoint's tensor names; a rule is keyed by
+# the name without its ``model.layers.<i|*>.`` prefix, and dims count on the
+# leaf without its stacked layer axis (held experts keep theirs: EP first).
+_CKPT_RULES: dict[str, tuple[int, ...]] = {
+    "model.embed_tokens.weight": (0,), "lm_head.weight": (1,),
+    "self_attn.q_proj.weight": (1,), "self_attn.kv_b_proj.weight": (1,),
+    "self_attn.o_proj.weight": (0,),
+    "mlp.gate_proj.weight": (1,), "mlp.up_proj.weight": (1,), "mlp.down_proj.weight": (0,),
+    "mlp.shared_experts.gate_proj.weight": (1,), "mlp.shared_experts.up_proj.weight": (1,),
+    "mlp.shared_experts.down_proj.weight": (0,),
+    "mlp.experts.*.gate_proj.weight": (0, 2), "mlp.experts.*.up_proj.weight": (0, 2),
+    "mlp.experts.*.down_proj.weight": (0, 1),
+}
+
 
 @dataclasses.dataclass(frozen=True)
 class ShardingRules:
@@ -122,6 +136,12 @@ def _pspec_for_leaf(path, leaf, rules: ShardingRules) -> P:
     if name in ("w_gate", "w_up", "w_down") and base_rank == 3:
         key = "moe." + name
     candidates = _PARAM_RULES.get(key, ())
+    if name.startswith("model.layers."):  # a checkpoint-named tensor
+        layer, _, key = name[len("model.layers."):].partition(".")
+        stacked = layer == "*"
+        candidates = _CKPT_RULES.get(key, ())
+    elif "." in name:
+        candidates = _CKPT_RULES.get(name, ())
     spec = [None] * leaf.ndim
     if rules.replicate_below:
         import math

@@ -64,6 +64,7 @@ def test_full_config_matches_assignment(arch):
         "hubert-xlarge": (48, 1280, 16, 16, 5120, 504),
         "jamba-1.5-large-398b": (72, 8192, 64, 8, 24576, 65536),
         "qwen2-vl-2b": (28, 1536, 12, 2, 8960, 151936),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 1408, 163840),
     }[arch]
     cfg = get_config(arch)
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff, cfg.vocab)
@@ -83,6 +84,16 @@ def test_full_config_matches_assignment(arch):
         assert cfg.qkv_bias and cfg.tie_embeddings
     if arch == "qwen2-vl-2b":
         assert cfg.rope == "mrope"
+    if arch == "moonlight-16b-a3b":
+        assert (cfg.attention, cfg.kv_lora_rank, cfg.head_dim, cfg.qk_rope_dim, cfg.v_head_dim) == (
+            "mla", 512, 128, 64, 128,
+        )
+        assert (cfg.n_experts, cfg.top_k, cfg.n_shared_experts, cfg.d_ff) == (64, 6, 2, 1408)
+        assert (cfg.first_dense, cfg.dense_d_ff, cfg.router, cfg.routed_scale) == (
+            1, 11264, "sigmoid", 2.446,
+        )
+        assert cfg.block_kinds() == ("attn_mlp",) + ("attn_moe",) * 26
+        assert cfg.held == (0, 64) and cfg.rope_theta == 5e4
 
 
 _DECODABLE = [a for a in ARCHS if a not in ("hubert-xlarge", "qwen2-vl-2b")]

@@ -22,12 +22,14 @@ from repro.kernels import ops
 
 PRESET = 3
 # Workloads that declare a Pallas kernel (gemm x4, maxflops x2, sort,
-# where, srad, softmax, convolution_im2col, lrn, connected, pooling).
+# where, srad, softmax, convolution_im2col, lrn, connected, pooling,
+# lm_prefill).
 PALLAS_WORKLOADS = (
     "gemm_bf16_nn", "gemm_bf16_tn", "gemm_f32_nn", "gemm_f32_tn",
     "maxflops_bf16", "maxflops_f32", "sort", "where", "srad", "softmax",
-    "convolution_im2col", "lrn", "connected", "pooling",
+    "convolution_im2col", "lrn", "connected", "pooling", "lm_prefill",
 )
+V5E_HBM = 16e9
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +80,9 @@ def test_declared_kernel_compiles_for_v5e(name, one_chip, monkeypatch):
     with ops.force_impl("pallas", workload.pallas_kernel):
         compiled = _compile(workload.fn, shapes, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+    # One call's inputs, outputs and temporaries fit the chip.
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes < V5E_HBM
 
 
 # The GEMM benchmark's size: blocks picked for 8192² must stay inside the
