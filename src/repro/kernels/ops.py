@@ -65,6 +65,7 @@ __all__ = [
     "sort_kv",
     "on_tpu",
     "force_impl",
+    "resolves_to_pallas",
     "tune_space",
     "PALLAS_OPS",
 ]
@@ -130,6 +131,14 @@ def _resolve(op: str, mode: Mode, blocks: dict) -> tuple[bool, bool, dict]:
             blocks = {**f_params, **blocks}
     use, interp = _use_pallas(mode)
     return use, interp, blocks
+
+
+def resolves_to_pallas(op: str) -> bool:
+    """Whether a ``mode="auto"`` call of ``op`` made here, at trace time and
+    under the current ``force_impl``, runs the Pallas kernel. For callers
+    that shape their operands differently for the kernel than for their own
+    XLA path."""
+    return _resolve(op, "auto", {})[0]
 
 
 def tune_space(op: str) -> tuple[dict, ...]:

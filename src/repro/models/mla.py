@@ -11,8 +11,15 @@ Per token, with ``H`` heads, latent rank ``r``, head widths ``n`` (nope),
   softmax, and the heads' values through W_o.
 
 The cache is the latent: [c | RoPE(k_rope)], r + e values a token per layer,
-not K and V per head. Prefill expands the latent into K and V and runs
-``sdpa`` (chunked online softmax); a decode step instead folds W_kv_b into
+not K and V per head. Prefill expands the latent into K and V per head and
+runs the attention core on one of two routes, whichever ``ops.attention``
+resolves to under the engine's ``impl``: on the Pallas route the fused
+flash kernel (``kernels/flash_attention.py``, heads-major operands, its own
+128-wide values against 192-wide queries and keys, the operands' dtype on
+the MXU, causal key blocks above the diagonal never visited); on the XLA
+route ``sdpa``'s chunked online softmax (``cfg.attn_chunk``), never the
+dense oracle. Both take their operands in ``cfg.score_dtype`` and keep the
+softmax and the accumulation in f32. A decode step instead folds W_kv_b into
 the query and the output ("absorbed" form), so it reads only the latent.
 
 Weights are the checkpoint's own tensors (``self_attn.q_proj.weight``, ...),
@@ -61,18 +68,28 @@ def _latent(p: dict, cfg: ArchConfig, x: jax.Array, positions: jax.Array):
 
 def attend(p: dict, cfg: ArchConfig, x: jax.Array, positions: jax.Array):
     """Full-sequence (prefill) MLA: x (B, T, d) -> (out (B, T, d), the
-    layer's cache entry (B, T, r + e))."""
+    layer's cache entry (B, T, r + e)).
+
+    The attention core is the Pallas flash kernel where ``ops.attention``
+    resolves to it, fed heads-major (B, H, T, ·) operands; elsewhere the
+    chunked ``sdpa``. Its dense oracle, ``ops.attention``'s ref branch,
+    would hold a (T, T) f32 score matrix per head and is never taken."""
     B, T, _ = x.shape
     H, n, r = cfg.n_heads, cfg.head_dim, cfg.kv_lora_rank
+    score_dtype = jnp.dtype(cfg.score_dtype)
     q_nope, q_rope, latent = _latent(p, cfg, x, positions)
     kv = mm(latent[..., :r], p[KV_B]).reshape(B, T, H, n + cfg.v_head_dim)
     k_rope = jnp.broadcast_to(latent[..., None, r:], q_rope.shape)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([kv[..., :n], k_rope], axis=-1)
-    out = sdpa(
-        q, k, kv[..., n:], causal=True, window=None, chunk=cfg.attn_chunk,
-        score_dtype=jnp.dtype(cfg.score_dtype), unroll_inner=cfg.unroll_inner,
-    )
+    if ops.resolves_to_pallas("attention"):
+        q, k, v = (jnp.swapaxes(a, 1, 2).astype(score_dtype) for a in (q, k, kv[..., n:]))
+        out = jnp.swapaxes(ops.attention(q, k, v, causal=True), 1, 2).astype(x.dtype)
+    else:
+        out = sdpa(
+            q, k, kv[..., n:], causal=True, window=None, chunk=cfg.attn_chunk,
+            score_dtype=score_dtype, unroll_inner=cfg.unroll_inner,
+        )
     return mm(out.reshape(B, T, H * cfg.v_head_dim), p[O]), latent
 
 

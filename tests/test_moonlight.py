@@ -11,9 +11,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.kernels import ops
-from repro.models import Model, deepseek_ref
+from repro.models import Model, deepseek_ref, mla
 from repro.models.model import STACK, ckpt_layout, dense_prefix
 from repro.models.moe import BIAS, ROUTER, SHARED, apply_moe_dropless, route_sigmoid, swiglu
 
@@ -65,6 +65,39 @@ def test_forward_and_latent_cache_match_the_reference(impl):
     for got, ref in zip(_latents(cfg, cache), latents, strict=True):
         np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(counts, np.stack(want_counts))
+
+
+def _avals(jaxpr):
+    """The abstract value of every intermediate of ``jaxpr``, sub-jaxprs
+    (scan bodies, called functions) included."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _avals(inner)
+
+
+def test_the_xla_route_never_holds_a_score_matrix_per_head():
+    """At preset 3 (8 prompts of 4,096 tokens) MLA prefill on the XLA route
+    stays on the chunked scan: no intermediate is (T, T) per head, as the
+    dense oracle's 8.6-GB score tensor would be. Traced, nothing run."""
+    cfg = get_config("moonlight-16b-a3b")
+    B, T = 8, 4096
+    params = {
+        k[len(STACK):]: jax.ShapeDtypeStruct(shape[1:], dt)
+        for k, (shape, dt, _) in ckpt_layout(cfg).items()
+        if k.startswith(STACK)
+    }
+    x = jax.ShapeDtypeStruct((B, T, cfg.d_model), jnp.bfloat16)
+    positions = jax.ShapeDtypeStruct((B, T), jnp.int32)
+    with ops.force_impl("ref"):
+        jaxpr = jax.make_jaxpr(lambda p, x, pos: mla.attend(p, cfg, x, pos))(params, x, positions)
+    shapes = [a.shape for a in _avals(jaxpr.jaxpr) if hasattr(a, "shape")]
+    assert any(s[-1:] == (cfg.attn_chunk,) for s in shapes)  # the scan's score blocks
+    # (B, T, H * (n + v)) is 4,096 wide too: a score tensor also has H.
+    assert not [s for s in shapes if s.count(T) >= 2 and cfg.n_heads in s]
 
 
 def test_prefill_then_decode_matches_the_full_forward():

@@ -97,13 +97,21 @@ def test_gemm_compiles_for_v5e_at_n8192(name, one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_flash_attention_compiles_for_v5e(one_chip):
+# Same-width heads, and Moonlight's MLA prefill (8 prompts of 4,096 tokens,
+# 192-wide q/k, 128-wide v): the picked blocks fit the scoped VMEM.
+@pytest.mark.parametrize(
+    "qk,v", [((1, 8, 2048, 128), (1, 8, 2048, 128)), ((8, 16, 4096, 192), (8, 16, 4096, 128))]
+)
+def test_flash_attention_compiles_for_v5e(one_chip, qk, v):
     from repro.kernels.flash_attention import flash_attention_pallas
 
-    q = jax.ShapeDtypeStruct((1, 8, 2048, 128), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct(qk, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(v, jnp.bfloat16)
     compiled = _compile(
         lambda q, k, v: flash_attention_pallas(q, k, v, causal=True),
-        (q, q, q),
+        (q, q, v),
         one_chip,
     )
     assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes < V5E_HBM
