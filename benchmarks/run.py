@@ -8,7 +8,6 @@ Prints ``name,us_per_call,derived`` CSV. Sections:
   fig_scaling — device-scaling sweep (sharded data-parallel placement)
   fig_concurrency — dispatch-lane speedup + co-location interference
   fig_batching — continuous batching: loop vs lanes vs dynamic goodput
-  fig_dist — distributed load generation: 1 vs N client processes
   fig_impl — XLA vs Pallas implementation axis (autotuned block sizes)
   fig_trace — per-stage engine time breakdown (obs layer, schema v8)
   table2   — per-layer kernel classification (Table II)
@@ -41,7 +40,6 @@ SECTION_NAMES = (
     "fig_scaling",
     "fig_concurrency",
     "fig_batching",
-    "fig_dist",
     "fig_impl",
     "fig_trace",
     "table2",
@@ -82,7 +80,6 @@ def main(argv=None) -> int:
         fig12_legacy_utilization,
         fig_batching,
         fig_concurrency,
-        fig_dist,
         fig_impl,
         fig_scaling,
         fig_trace,
@@ -100,7 +97,6 @@ def main(argv=None) -> int:
         "fig_scaling": lambda: fig_scaling.rows(preset=args.preset),
         "fig_concurrency": lambda: fig_concurrency.rows(preset=args.preset),
         "fig_batching": lambda: fig_batching.rows(preset=args.preset),
-        "fig_dist": lambda: fig_dist.rows(preset=args.preset),
         "fig_impl": lambda: fig_impl.rows(preset=args.preset),
         "fig_trace": lambda: fig_trace.rows(preset=args.preset),
         "table2": lambda: table2_dnn_kernels.rows(preset=max(args.preset, 1)),

@@ -18,11 +18,10 @@ from repro.check.schema import update_fingerprint
 _EPILOG = """\
 rules (see --list-rules for one-line summaries):
   workload-contract   bench registrations vs kernels.PALLAS_OPS
-  cache-key           plan/placement axes join both cache keys
+  cache-key           plan/placement axes join the cache key
   stage-discipline    _timed_stage coverage + zero-overhead hot loops
   schema-drift        BenchmarkRecord shape vs committed fingerprint
-  concurrency         lock-owning serve/obs/dist classes mutate under the lock
-  dist-proto          every dist/proto.py message registered + round-trips
+  concurrency         lock-owning serve/obs classes mutate under the lock
 
 suppressing a finding:
   put `# repro-check: ignore[<rule>]` on the flagged line or the line

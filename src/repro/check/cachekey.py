@@ -1,4 +1,4 @@
-"""cache-key: every axis that reaches lowering joins both cache keys.
+"""cache-key: every axis that reaches lowering joins the cache key.
 
 A plan/placement axis that changes what gets compiled but is missing
 from the cache key makes the warm path serve a stale executable as fresh
@@ -6,18 +6,15 @@ data — the worst failure mode a benchmarking suite has, because the
 numbers still look plausible. This rule makes "added an axis, forgot the
 key" a CI failure:
 
-* every non-``self`` parameter of ``Engine._cache_key`` and
-  ``Engine._bucket_key`` must be referenced inside the function (a
-  parameter that does not reach the key is an axis that was plumbed in
-  and then dropped);
+* every non-``self`` parameter of ``Engine._bucket_key``, the one key
+  builder, must be referenced inside the function (a parameter that
+  does not reach the key is an axis that was plumbed in and then
+  dropped);
 * every field of the ``Placement`` dataclass (parsed from ``plan.py``)
-  must appear as ``placement.<field>`` in *both* key builders;
-* the two builders' key tuples must have the same arity — they describe
-  the same executable identity, so one growing without the other means a
-  new axis joined only one of them;
+  must appear as ``placement.<field>`` in the key builder;
 * every ``*.disk_cache.<load/store/...>`` call site must pass a key that
-  was produced by ``_cache_key``/``_bucket_key`` (or arrived as a
-  parameter named ``key``), never an ad-hoc tuple;
+  was produced by ``_bucket_key`` (or arrived as a parameter named
+  ``key``), never an ad-hoc tuple;
 * ``HloDiskCache._path`` must hash ``repr(key)`` of the whole key —
   subscripting the key there would silently drop axes from the digest.
 """
@@ -34,7 +31,7 @@ _ENGINE_FILE = "src/repro/core/engine.py"
 _PLAN_FILE = "src/repro/core/plan.py"
 _HLOCACHE_FILE = "src/repro/core/hlocache.py"
 
-_KEY_BUILDERS = ("_cache_key", "_bucket_key")
+_KEY_BUILDERS = ("_bucket_key",)
 _DISK_CACHE_METHODS = {"load", "store", "load_tuned", "store_tuned"}
 
 
@@ -67,8 +64,8 @@ def _find_methods(tree: ast.Module, names: tuple[str, ...]) -> dict[str, ast.Fun
 
 def _check_builder(
     fn: ast.FunctionDef, placement_fields: set[str]
-) -> tuple[list[Finding], int]:
-    """Findings for one key-builder, plus the arity of its key tuple."""
+) -> list[Finding]:
+    """Findings for one key-builder."""
     findings: list[Finding] = []
 
     params = [
@@ -105,12 +102,7 @@ def _check_builder(
                     "axis must join the cache key",
                 )
             )
-
-    arity = max(
-        (len(n.elts) for n in ast.walk(fn) if isinstance(n, ast.Tuple)),
-        default=0,
-    )
-    return findings, arity
+    return findings
 
 
 def _own_nodes(fn: ast.FunctionDef):
@@ -165,7 +157,7 @@ def _check_disk_cache_sites(tree: ast.Module) -> list[Finding]:
                         _ENGINE_FILE,
                         node.lineno,
                         f"disk_cache.{parts[-1]}() key must be bound from "
-                        "_cache_key()/_bucket_key(), not built ad hoc — "
+                        "_bucket_key(), not built ad hoc — "
                         "ad-hoc keys drift from the compile-cache key",
                     )
                 )
@@ -233,8 +225,9 @@ def _check_hlocache_path(ctx: Context) -> list[Finding]:
 
 @checker(
     RULE,
-    "every ExecutionPlan/Placement axis joins both the compile-cache and "
-    "HLO-disk-cache keys; disk-cache call sites use builder-produced keys",
+    "every ExecutionPlan/Placement axis joins the key shared by the "
+    "compile cache and the HLO disk cache; disk-cache call sites use "
+    "builder-produced keys",
 )
 def check_cache_key(ctx: Context) -> list[Finding]:
     findings: list[Finding] = []
@@ -242,7 +235,6 @@ def check_cache_key(ctx: Context) -> list[Finding]:
     if tree is not None:
         placement_fields = _placement_fields(ctx)
         builders = _find_methods(tree, _KEY_BUILDERS)
-        arities: dict[str, int] = {}
         for name in _KEY_BUILDERS:
             fn = builders.get(name)
             if fn is None:
@@ -255,20 +247,7 @@ def check_cache_key(ctx: Context) -> list[Finding]:
                     )
                 )
                 continue
-            fn_findings, arity = _check_builder(fn, placement_fields)
-            findings.extend(fn_findings)
-            arities[name] = arity
-        if len(arities) == len(_KEY_BUILDERS):
-            a, b = (arities[n] for n in _KEY_BUILDERS)
-            if a != b:
-                findings.append(
-                    _finding(
-                        _ENGINE_FILE,
-                        builders[_KEY_BUILDERS[1]].lineno,
-                        f"_cache_key builds a {a}-axis key but _bucket_key "
-                        f"builds {b} — a new axis joined only one of them",
-                    )
-                )
+            findings.extend(_check_builder(fn, placement_fields))
         findings.extend(_check_disk_cache_sites(tree))
     findings.extend(_check_hlocache_path(ctx))
     return findings

@@ -1,6 +1,6 @@
 """concurrency: lock-owning classes mutate their containers under the lock.
 
-Scope: classes under ``serve/``, ``obs/`` and ``dist/`` whose ``__init__`` creates a
+Scope: classes under ``serve/`` and ``obs/`` whose ``__init__`` creates a
 ``threading.Lock``/``RLock``. For those classes, the containers also
 created in ``__init__`` (list/dict/set/deque literals or constructors)
 are treated as lock-guarded shared state: any mutation of them from a
@@ -24,7 +24,7 @@ from repro.check.core import Context, Finding, checker, dotted_name
 
 RULE = "concurrency"
 
-_SCOPES = ("src/repro/serve", "src/repro/obs", "src/repro/dist")
+_SCOPES = ("src/repro/serve", "src/repro/obs")
 
 _CONTAINER_CTORS = {"list", "dict", "set", "deque", "defaultdict", "OrderedDict"}
 _MUTATORS = {

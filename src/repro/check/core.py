@@ -165,7 +165,6 @@ def _load_rules() -> None:
         cachekey,
         concurrency,
         contracts,
-        distproto,
         schema,
         stages,
     )

@@ -199,11 +199,8 @@ class Engine:
         self.cache = cache if cache is not None else CompileCache()
         # Optional cross-process persistence of serialized executables —
         # warm entries skip retracing and XLA compilation. None =
-        # in-process only. The raw root is kept so distributed client
-        # processes can be pointed at the same cache. JAX's own
-        # persistent compilation cache is placed by the entry points
-        # (enable_compile_cache), never here.
-        self.cache_dir = cache_dir
+        # in-process only. JAX's own persistent compilation cache is
+        # placed by the entry points (enable_compile_cache), never here.
         self.disk_cache = HloDiskCache(cache_dir) if cache_dir else None
         # Structured tracing (repro.obs): every _stage_* becomes a span,
         # serve completions and batch executions become retrospective
@@ -213,28 +210,6 @@ class Engine:
         self.tracer = tracer if tracer is not None else NULL_TRACER
 
     # -- stages ------------------------------------------------------------
-
-    def _cache_key(
-        self,
-        spec: BenchmarkSpec,
-        plan: ExecutionPlan,
-        preset: int,
-        backward: bool,
-        placement: Placement,
-        impl: str = "xla",
-        tuned_params: dict | None = None,
-    ) -> CacheKey:
-        return (
-            spec.name,
-            preset,
-            tuple(sorted(plan.overrides_for(spec.name).items())),
-            backward,
-            jax.default_backend(),
-            placement.devices,
-            placement.mode,
-            impl,
-            tuple(sorted((tuned_params or {}).items())),
-        )
 
     def _resolve_impl(
         self, workload: Workload, plan: ExecutionPlan, backward: bool
@@ -342,8 +317,9 @@ class Engine:
         fn = workload.fn_bwd if backward else workload.fn
         if backward and fn is None:
             raise ValueError(f"workload {workload.name!r} has no backward pass")
-        key = self._cache_key(
-            spec, plan, preset, backward, placement, impl, tuned_params
+        key = self._bucket_key(
+            spec, preset, plan.overrides_for(spec.name), placement, impl,
+            tuned_params, 1, backward=backward,
         )
 
         def build() -> _CacheEntry:
@@ -435,8 +411,9 @@ class Engine:
             # Nothing to sweep (kernels without block params): the single
             # candidate wins by default, at zero trials.
             return dict(space[0]), 0, 0.0
-        base_key = self._cache_key(
-            spec, plan, preset, backward, placement, impl
+        base_key = self._bucket_key(
+            spec, preset, plan.overrides_for(spec.name), placement, impl,
+            None, 1, backward=backward,
         )
         use_disk = self.disk_cache is not None and placement.devices == 1
         if use_disk:
@@ -665,16 +642,18 @@ class Engine:
         impl: str,
         tuned_params: dict | None,
         width: int,
+        backward: bool = False,
     ) -> tuple:
-        """Compile-cache key for one (shape bucket, batch width) serve
-        executable. Width 1 uses the ordinary key shape, so a bucket at
-        the plan's own preset/overrides *shares the measure stage's
-        executable*; wider programs append ("vmap", width)."""
+        """The compile-cache key, for every program the engine compiles:
+        the measure stage's (width 1, either pass) and each (shape bucket,
+        batch width) serve executable. Width 1 uses the ordinary key
+        shape, so a bucket at the plan's own preset/overrides *shares the
+        measure stage's executable*; wider programs append ("vmap", width)."""
         base = (
             spec.name,
             bucket_preset,
             tuple(sorted(merged_overrides.items())),
-            False,
+            backward,
             jax.default_backend(),
             placement.devices,
             placement.mode,
@@ -893,26 +872,6 @@ class Engine:
                 spec, plan, preset, placement, impl, tuned_params
             )
             return stats, None, None, []
-        if serve.client_procs > 0:
-            # Distributed load generation (repro.dist): N client
-            # processes, each compiling through the shared cache dir and
-            # replaying its own seeded sub-schedule; the launcher merges
-            # their completion streams into one stats object carrying
-            # per-process QPS.
-            from repro.dist.launcher import run_distributed
-
-            stats = run_distributed(
-                benchmark=spec.name,
-                preset=preset,
-                overrides=dict(plan.overrides_for(spec.name)),
-                serve=serve,
-                seed=plan.seed,
-                devices=placement.devices,
-                placement_mode=placement.mode,
-                impl=impl,
-                cache_dir=self.cache_dir,
-            )
-            return stats, None, None, []
         call = lambda: entry.executable(*args)  # noqa: E731
         if serve.colocate is None:
             return self._serve_call(call, serve, plan.seed), None, None, []
@@ -991,7 +950,10 @@ class Engine:
             # Effective placement/impl == requested without building the
             # workload (xla is every workload's fallback).
             cached = self.cache.peek(
-                self._cache_key(spec, plan, preset, backward, requested)
+                self._bucket_key(
+                    spec, preset, plan.overrides_for(spec.name), requested,
+                    "xla", None, 1, backward=backward,
+                )
             )
             if cached is not None and cached.info is not None:
                 self.cache.hits += 1
@@ -1002,7 +964,10 @@ class Engine:
         args = workload.make_inputs(plan.seed)
         placement = self._resolve_placement(workload, args, requested)
         cached = self.cache.peek(
-            self._cache_key(spec, plan, preset, backward, placement, impl)
+            self._bucket_key(
+                spec, preset, plan.overrides_for(spec.name), placement, impl,
+                None, 1, backward=backward,
+            )
         )
         if cached is not None and cached.info is not None:
             self.cache.hits += 1
@@ -1063,10 +1028,6 @@ class Engine:
                 get_benchmark(plan.serve.colocate)
             except KeyError as e:
                 raise PlanError(str(e)) from None
-        if plan.serve is not None and plan.serve.client_procs > 0:
-            from repro.dist.launcher import refuse_children_on_device
-
-            refuse_children_on_device()
         if plan.serve is not None and plan.serve.is_mixed and want > 1:
             raise PlanError(
                 "mixed-shape serving (mix/trace/batcher dispatch) is "

@@ -92,8 +92,7 @@ def _topology_token() -> str:
     executable is compiled for a topology, so a different accelerator, a
     different forced host-device count, or a different ``jax.distributed``
     process count must get its own cache directory, not a
-    deserialization failure. (Distributed serving clients share the
-    launcher's environment, so they land in the same directory.)"""
+    deserialization failure."""
     devices = jax.devices()
     kind = re.sub(r"[^A-Za-z0-9_.-]+", "_", devices[0].device_kind) or "unknown"
     return f"{kind}x{len(devices)}p{jax.process_count()}"

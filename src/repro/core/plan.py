@@ -183,16 +183,6 @@ class ServeSpec:
       long the oldest queued request may wait before a partial batch
       dispatches anyway, and the largest vmap width (widths are powers
       of two up to it).
-    - ``client_procs``: distributed load generation (``repro.dist``).
-      0 (default) generates all load in this process; N > 0 spawns N
-      client *processes*, each replaying a seeded per-process
-      sub-schedule (``SeedSequence.spawn`` off the plan seed — the merged
-      stream is still Poisson at ``qps`` and byte-identical per seed) and
-      streaming completion stamps back over a local socket for merged
-      percentile accounting, so offered QPS scales past one Python
-      process's dispatch ceiling. Open-loop only; within each process the
-      sub-schedule is dispatched single-threaded across ``lanes`` lanes.
-
     The engine runs serving as a stage after ``measure``. Dispatch
     ``lanes`` without a mix calls the *same cached executable* the timer
     used — never a recompile (and a sharded plan serves the sharded
@@ -214,7 +204,6 @@ class ServeSpec:
     trace: str | None = None
     batch_budget_us: float = 2000.0
     max_batch: int = 8
-    client_procs: int = 0
 
     def __post_init__(self) -> None:
         if self.mix is not None:
@@ -296,35 +285,6 @@ class ServeSpec:
                 "mixed-shape serving cannot be combined with colocate "
                 f"(got colocate={self.colocate!r})"
             )
-        if self.client_procs < 0:
-            raise PlanError(
-                f"client_procs must be >= 0, got {self.client_procs}"
-            )
-        if self.client_procs > 0:
-            if self.mode != "open":
-                raise PlanError(
-                    "distributed client processes replay seeded arrival "
-                    "sub-schedules; client_procs requires mode='open', "
-                    f"got {self.mode!r}"
-                )
-            if mixed:
-                raise PlanError(
-                    "distributed serving covers the classic lanes path; "
-                    "client_procs cannot be combined with mix/trace/"
-                    f"dispatch != 'lanes' (got dispatch={self.dispatch!r})"
-                )
-            if self.colocate is not None:
-                raise PlanError(
-                    "co-location is a closed-loop single-process "
-                    f"measurement; got colocate={self.colocate!r} with "
-                    f"client_procs={self.client_procs}"
-                )
-            if self.client != "single":
-                raise PlanError(
-                    "each distributed client process dispatches its "
-                    "sub-schedule from one thread; client_procs requires "
-                    f"client='single', got {self.client!r}"
-                )
 
     @property
     def is_mixed(self) -> bool:

@@ -75,16 +75,9 @@ __all__ = [
 #     JSONL writer re-emits the final metadata as a second meta line at
 #     close (load_run is last-meta-wins), so streamed reports carry
 #     end-of-run counter totals without giving up streaming.
-# v9: distributed serving — client_procs (how many load-generation client
-#     processes replayed seeded sub-schedules; 0/None = in-process
-#     serving) and proc_qps (per-process achieved QPS over the merged
-#     completion stream, the column that shows whether every client
-#     pulled its weight). Merged latency columns reuse the existing
-#     percentile fields: the launcher computes them over the
-#     concatenation of the per-process streams, which tests pin as
-#     identical to a single stream's percentiles. The ServeSpec in
-#     RunMetadata carries client_procs.
-SCHEMA_VERSION = 9
+# v9: two per-process columns of the multi-process load generator.
+# v10: the v9 columns left with the multi-process load generator.
+SCHEMA_VERSION = 10
 
 
 class ReportError(ValueError):
@@ -208,10 +201,6 @@ class BenchmarkRecord:
     # bucket label -> {"requests", "p50_us", "p95_us", "p99_us"}; a plain
     # dict (not a dataclass) so JSON round-trips it unchanged.
     bucket_latency_us: dict | None = None
-    # Distributed serving columns (schema v9) — None unless the row was
-    # served through repro.dist (ServeSpec.client_procs > 0).
-    client_procs: int | None = None  # load-generation client processes
-    proc_qps: list[float] | None = None  # per-process achieved QPS
     # Observability (schema v8): stage name -> wall microseconds this row
     # spent in that stage (build/place shared timings are copied into
     # every pass's row). Always collected — the perf_counter pairs cost
@@ -252,12 +241,6 @@ class BenchmarkRecord:
         self.lane_qps = (
             list(stats.lane_qps) if stats.lane_qps is not None else None
         )
-        # Distributed-serving accounting (schema v9). getattr-tolerant:
-        # only DistLatencyStats (repro.dist.launcher) carries these.
-        procs = getattr(stats, "client_procs", None)
-        self.client_procs = procs if procs else None
-        proc_qps = getattr(stats, "proc_qps", None)
-        self.proc_qps = list(proc_qps) if proc_qps is not None else None
         # Continuous-batching accounting (schema v7). getattr-tolerant so
         # plain stats objects without the batching fields still fold in.
         self.serve_dispatch = dispatch
@@ -456,8 +439,6 @@ class BenchmarkRecord:
                 )
             if self.dispatch_overhead_us is not None:
                 serve += f";dispatch_us={self.dispatch_overhead_us:.1f}"
-            if self.client_procs:
-                serve += f";client_procs={self.client_procs}"
             if self.serve_dispatch is not None and self.serve_dispatch != "lanes":
                 serve += f";dispatch={self.serve_dispatch}"
             if self.batch_occupancy is not None:

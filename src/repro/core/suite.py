@@ -109,11 +109,6 @@ examples:
   # later runs (any --serve-dispatch) replay the identical trace
   python -m repro.core.suite --names pathfinder --serve open --qps 500 \\
       --serve-mix "0@2,1@1" --serve-trace /tmp/mix.jsonl --serve-dispatch loop
-  # distributed load generation: 4 client processes, each replaying its
-  # own seeded sub-schedule, merged percentiles + per-process QPS in the
-  # row; the shared cache dir makes the warm run zero-compile everywhere
-  python -m repro.core.suite --names pathfinder --serve open --qps 400 \\
-      --client-procs 4 --cache-dir /tmp/repro-cache
   # structured tracing: every engine stage, serve request, and batcher
   # flush becomes a span in a Chrome trace-event file
   python -m repro.core.suite --names gemm_f32_nn --serve closed \\
@@ -138,21 +133,6 @@ serving semantics:
   The threaded client splits the arrival process into per-lane Poisson
   sub-schedules from seeded child RNGs: the merged stream still offers
   the target QPS and is deterministic for a fixed --seed.
-
-distributed serving (--client-procs N):
-  the same SeedSequence split, applied across *processes*: process k of N
-  replays sub-schedule k of an N-way split of the target load, so the
-  merged arrival stream is Poisson at --qps and byte-identical per --seed
-  (replayable via the serve-trace JSONL format), while load generation
-  scales past one Python process's dispatch ceiling — the point where
-  adding processes stops raising sustained QPS is the measured ceiling.
-  Merged percentiles are computed over the *concatenation* of the
-  per-process completion streams on one shared clock epoch — identical,
-  by construction and by test, to the percentiles of a single stream —
-  and rows carry client_procs plus per-process proc_qps. Each client
-  process compiles through the shared --cache-dir, so a warm distributed
-  run performs zero XLA compiles in every process (asserted from the
-  "# dist-cache" stderr line next to "# hlocache:").
 
 batching semantics:
   --serve-mix is a comma-separated list of PRESET[/PARAM=VALUE...][@WEIGHT]
@@ -339,7 +319,6 @@ def _parse_serve(args) -> ServeSpec | None:
         "--serve-trace": args.serve_trace,
         "--batch-latency-budget": args.batch_latency_budget,
         "--max-batch": args.max_batch,
-        "--client-procs": args.client_procs,
     }
     if args.serve is None and args.colocate is None:
         stray = [flag for flag, value in tuning.items() if value is not None]
@@ -378,11 +357,6 @@ def _parse_serve(args) -> ServeSpec | None:
             else spec.batch_budget_us
         ),
         max_batch=args.max_batch if args.max_batch is not None else spec.max_batch,
-        client_procs=(
-            args.client_procs
-            if args.client_procs is not None
-            else spec.client_procs
-        ),
     )
 
 
@@ -441,16 +415,6 @@ def main(argv: Sequence[str] | None = None) -> int:
                     help="latency SLO in microseconds; rows gain "
                          "goodput_qps (completions with latency <= SLO "
                          "per second; latency == SLO counts as good)")
-    ap.add_argument("--client-procs", type=int, default=None, metavar="N",
-                    help="distributed load generation: spawn N client "
-                         "processes, each replaying a seeded per-process "
-                         "sub-schedule (the merged stream is still Poisson "
-                         "at --qps, byte-identical per --seed) and "
-                         "streaming completion stamps back for merged "
-                         "percentiles; requires --serve open. Rows carry "
-                         "client_procs and per-process proc_qps; share "
-                         "--cache-dir so a warm run compiles nothing in "
-                         "any process")
     ap.add_argument("--serve-dispatch", choices=SERVE_DISPATCH, default=None,
                     help="how requests map onto device programs: classic "
                          "N-lane dispatch (lanes, default), or the mixed-"

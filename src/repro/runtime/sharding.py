@@ -28,8 +28,6 @@ __all__ = [
     "batch_pspec",
     "make_activation_sharder",
     "data_mesh",
-    "init_distributed",
-    "host_data_mesh",
     "replicate",
     "workload_pspecs",
     "shard_applies",
@@ -270,81 +268,6 @@ def data_mesh(n_devices: int | None = None, axis: str = "data") -> Mesh:
     import numpy as np
 
     return Mesh(np.asarray(devs[:n]), (axis,))
-
-
-def init_distributed(
-    coordinator_address: str | None = None,
-    num_processes: int | None = None,
-    process_id: int | None = None,
-) -> bool:
-    """Join a ``jax.distributed`` multi-process topology, gated by backend.
-
-    On real multi-host hardware (TPU/GPU) this wraps
-    ``jax.distributed.initialize`` so ``jax.devices()`` becomes the
-    *global* device list and :func:`data_mesh` / :func:`host_data_mesh`
-    span processes. On the CPU backend XLA cannot execute multi-process
-    computations ("Multiprocess computations aren't implemented on the
-    CPU backend"), so this returns False without initializing — CI fakes
-    the topology instead: one process, ``xla_force_host_platform_
-    device_count=N``, and :func:`host_data_mesh` partitioning the forced
-    devices into host groups. Returns True when the distributed runtime
-    was (or already is) initialized.
-    """
-    if jax.default_backend() == "cpu":
-        return False
-    if jax.process_count() > 1:  # already initialized by the launcher/env
-        return True
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
-    except (RuntimeError, ValueError):
-        # Already initialized, or a single-process environment with no
-        # coordinator: both mean "use what jax already has".
-        pass
-    return jax.process_count() > 1
-
-
-def host_data_mesh(
-    n_hosts: int,
-    devices_per_host: int | None = None,
-    axes: tuple[str, str] = ("host", "data"),
-) -> Mesh:
-    """A 2-axis ``(host, data)`` mesh partitioning the visible devices
-    into ``n_hosts`` contiguous groups — the multi-host data-mesh shape.
-
-    Under an initialized ``jax.distributed`` runtime the device list is
-    global and the host axis aligns with processes (JAX orders global
-    devices by process); on CI the same topology is faked in one process
-    by forcing N host devices (``xla_force_host_platform_device_count``)
-    and grouping them here — the SNIPPETS idiom the distributed tests and
-    the ``--dist`` smoke leg run under. Contiguous grouping means the
-    ``data`` axis varies fastest within a host, so collectives over
-    ``data`` stay host-local and collectives over ``host`` model the
-    cross-host hop.
-    """
-    if n_hosts < 1:
-        raise ValueError(f"n_hosts must be >= 1, got {n_hosts}")
-    devs = jax.devices()
-    per = devices_per_host
-    if per is None:
-        if len(devs) % n_hosts:
-            raise ValueError(
-                f"{len(devs)} devices do not divide into {n_hosts} hosts; "
-                "pass devices_per_host explicitly"
-            )
-        per = len(devs) // n_hosts
-    need = n_hosts * per
-    if need > len(devs):
-        raise ValueError(
-            f"requested {n_hosts} hosts x {per} devices = {need}, "
-            f"but only {len(devs)} available"
-        )
-    import numpy as np
-
-    return Mesh(np.asarray(devs[:need]).reshape(n_hosts, per), axes)
 
 
 def replicate(tree: Any, mesh: Mesh) -> Any:

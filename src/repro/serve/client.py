@@ -230,7 +230,8 @@ def run_closed_loop_threaded(
     striped (lane k issues k, k+N, k+2N, ...) so they stay globally
     unique without cross-thread coordination; each lane marks its first
     ``ceil(warmup / n_lanes)`` requests as warmup, covering at least the
-    requested pipeline-fill exclusion. ``max_requests`` is an exact total
+    requested pipeline-fill exclusion, and issues past the window until
+    it has one request past them. ``max_requests`` is an exact total
     cap (as in the single-threaded client): it is pre-split across lanes,
     the first ``max_requests % n_lanes`` lanes taking one extra request.
     """
@@ -265,7 +266,9 @@ def run_closed_loop_threaded(
                     tid=f"lane {lane_index}",
                     lane=lane_index,
                 ):
-                    while time.perf_counter() < deadline:
+                    while (
+                        time.perf_counter() < deadline or i <= per_lane_warmup
+                    ):
                         if cap is not None and i >= cap:
                             break
                         req = Request(

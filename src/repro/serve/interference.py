@@ -70,7 +70,8 @@ def colocate_closed_loop(
     Each of the K tenants gets ``n_lanes // K`` lanes (min 1) and
     ``concurrency // K`` in-flight slots (min 1), so total pressure on the
     device matches a single-tenant run at the same ServeSpec — the
-    difference in latency is the interference.
+    difference in latency is the interference. The loop runs past the
+    window until every tenant has issued one request past its ``warmup``.
     """
     if not calls:
         raise ValueError("colocate_closed_loop needs at least one tenant")
@@ -84,7 +85,7 @@ def colocate_closed_loop(
     completions: dict[str, list[Completion]] = {name: [] for name in calls}
     counters = {name: 0 for name in calls}
     deadline = time.perf_counter() + duration_s
-    while time.perf_counter() < deadline:
+    while time.perf_counter() < deadline or min(counters.values()) <= warmup:
         for name, (call, lanes) in tenants.items():
             i = counters[name]
             req = Request(index=i, arrival_s=0.0, warmup=i < warmup)

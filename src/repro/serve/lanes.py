@@ -283,13 +283,15 @@ def run_closed_loop(
 
     The next request is issued as soon as the least-loaded lane has a free
     slot; a full lane blocks on its own oldest result, which *is* the slot
-    freeing up. The first ``warmup`` requests are marked for exclusion.
+    freeing up. The first ``warmup`` requests are marked for exclusion,
+    and the loop runs past the window until one request past them has
+    been issued, so a slow host still yields a measured request.
     """
     lanes = LaneSet(n_lanes, lane_depth(concurrency, n_lanes))
     completions: list[Completion] = []
     deadline = time.perf_counter() + duration_s
     index = 0
-    while time.perf_counter() < deadline:
+    while time.perf_counter() < deadline or index <= warmup:
         if max_requests is not None and index >= max_requests:
             break
         req = Request(index=index, arrival_s=0.0, warmup=index < warmup)

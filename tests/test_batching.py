@@ -13,6 +13,7 @@ flaky-timing policy.
 import dataclasses
 import json
 import os
+import time
 
 import pytest
 
@@ -302,6 +303,76 @@ def test_dynamic_batch_wider_than_inflight_cap_dispatches_alone():
     assert report.occupancy == 1.0
 
 
+def test_dynamic_zero_budget_dispatches_spaced_arrivals_at_width_one():
+    """budget_s=0 is eager dispatch: each request, 100 ms apart, goes out
+    alone even though wider programs exist. The same stream under a long
+    budget waits for the end of the stream and goes out as one batch."""
+    calls, dispatched = _counting_calls(["a"], [1, 2, 4])
+    sched = _instant(
+        Request(index=i, arrival_s=0.1 * i, bucket="a") for i in range(4)
+    )
+    eager = serve_dynamic(calls, sched, budget_s=0.0, concurrency=32)
+    assert sorted(c.index for c in eager.completions) == [0, 1, 2, 3]
+    assert [(b.width, b.filled) for b in eager.batches] == [(1, 1)] * 4
+    assert dispatched == [("a", 1)] * 4
+    dispatched.clear()
+    held = serve_dynamic(calls, sched, budget_s=10.0, concurrency=32)
+    assert [(b.width, b.filled) for b in held.batches] == [(4, 4)]
+
+
+def test_dynamic_interleaved_buckets_never_share_a_batch():
+    """Two buckets arriving interleaved, each with its own compiled
+    widths: a batch only ever holds one bucket's requests, and a partial
+    batch pads to the smallest width *its own* bucket compiled."""
+    calls = {}
+    dispatched = []
+    for bucket, widths in (("a", (1, 4)), ("b", (1, 2))):
+        calls[bucket] = {
+            w: (lambda b=bucket, w=w: dispatched.append((b, w)))
+            for w in widths
+        }
+    buckets = "abababaa"  # a: 5 requests, b: 3
+    sched = _instant(
+        Request(index=i, arrival_s=0.0, bucket=b) for i, b in enumerate(buckets)
+    )
+    report = serve_dynamic(calls, sched, budget_s=10.0, concurrency=32)
+    assert sorted(c.index for c in report.completions) == list(range(8))
+    for c in report.completions:
+        assert c.bucket == buckets[c.index]
+    # a: a full 4, then its flushed single; b: a full 2, then its single.
+    assert dispatched == [("a", 4), ("b", 2), ("a", 1), ("b", 1)]
+    for batch in report.batches:
+        assert batch.width in calls[batch.bucket]
+    assert report.occupancy == 1.0
+
+
+def test_dynamic_latency_counts_from_the_due_time_of_a_late_admission():
+    """The first call holds the loop for 50 ms, so requests due at 5 and
+    10 ms are admitted late. Each completion's t_submit is still its due
+    time (t0 + arrival_s), so the wait for admission is latency."""
+    slept = []
+
+    def slow_first():
+        if not slept:
+            slept.append(True)
+            time.sleep(0.05)
+
+    arrivals = (0.0, 0.005, 0.010)
+    sched = _instant(
+        Request(index=i, arrival_s=t, bucket="a") for i, t in enumerate(arrivals)
+    )
+    report = serve_dynamic({"a": {1: slow_first}}, sched, budget_s=0.0)
+    by_index = sorted(report.completions, key=lambda c: c.index)
+    assert [c.index for c in by_index] == [0, 1, 2]
+    t0 = by_index[0].t_submit - arrivals[0]
+    for c, due in zip(by_index, arrivals):
+        assert c.t_submit == pytest.approx(t0 + due, abs=1e-9)
+    # The held-up requests finish after the 50 ms call, and their latency
+    # includes the wait from their due time.
+    for c, due in zip(by_index[1:], arrivals[1:]):
+        assert c.t_done - c.t_submit >= 0.05 - due
+
+
 # -- ServeSpec mixed validation --------------------------------------------
 
 
@@ -400,6 +471,25 @@ def test_engine_mixed_serve_precompiles_every_bucket_width():
     assert eng.cache.misses == 5
     eng.run(plan)
     assert eng.cache.misses == 5  # warm in-process rerun: all hits
+
+
+def test_engine_mixed_bucket_at_plan_preset_reuses_the_measure_executable():
+    """A bucket at the plan's own preset and overrides shares the measure
+    stage's cache key at width 1: the 2 buckets x widths {1, 2} table
+    costs 4 compiles in all, the measured program included, and the
+    shared width-1 program is a cache hit."""
+    from repro.core.engine import Engine
+
+    mix = (
+        ShapeBucket(preset=0),
+        ShapeBucket(preset=0, overrides=(("cols", 64),)),
+    )
+    eng = Engine()
+    plan = ExecutionPlan(names=("pathfinder",), serve=_mixed_serve(mix=mix), **FAST)
+    res = eng.run(plan)
+    assert res.records[0].status == "ok", res.records[0].error
+    assert eng.cache.misses == 4
+    assert eng.cache.hits >= 1
 
 
 def test_engine_mixed_trace_replay_pins_the_load(tmp_path):

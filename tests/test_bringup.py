@@ -109,25 +109,6 @@ def test_suite_main_places_the_cache_from_cache_dir(monkeypatch, tmp_path):
     assert rc == 0 and calls == [str(tmp_path)]
 
 
-def test_client_procs_refused_off_cpu_before_any_child(monkeypatch, capsys):
-    from repro.core import suite
-    from repro.dist import launcher
-
-    def no_child(*a, **kw):
-        raise AssertionError("a client process was started")
-
-    monkeypatch.setattr(launcher.subprocess, "Popen", no_child)
-    monkeypatch.setattr(suite, "enable_compile_cache", lambda cache_dir=None: None)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    rc = suite.main([
-        "--names", "pathfinder", "--serve", "open", "--qps", "100",
-        "--client-procs", "2", "--iters", "1", "--warmup", "0",
-        "--no-backward",
-    ])
-    assert rc == 2
-    assert "would need the tpu device this process holds" in capsys.readouterr().err
-
-
 @pytest.mark.parametrize(
     "platform,kind,want",
     [("tpu", "TPU v5 lite", TPUv5e), ("cpu", "cpu", TPUv5e), ("tpu", "TPU v4", None)],

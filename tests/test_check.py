@@ -136,11 +136,8 @@ def cachekey_fixture(tmp_path: Path) -> Path:
             """,
             "src/repro/core/engine.py": """\
                 class Engine:
-                    def _cache_key(self, spec, preset, placement, impl):
+                    def _bucket_key(self, spec, preset, placement, impl):
                         return (spec, preset, placement.devices)
-
-                    def _bucket_key(self, spec, preset, placement):
-                        return (spec, preset, placement.devices, placement.mode)
 
                     def load(self, spec):
                         return self.disk_cache.load((spec, "adhoc"), None)
@@ -162,7 +159,6 @@ def test_cache_key_fires(tmp_path):
     msgs = messages(findings)
     assert "'impl' never reaches the key" in msgs
     assert "omits Placement.mode" in msgs
-    assert "axis joined only one of them" in msgs  # 3- vs 4-arity key tuples
     assert "not built ad hoc" in msgs
     assert "must not subscript the key" in msgs
     assert main(["--root", str(root), "--rules", "cache-key"]) == 1
@@ -173,15 +169,12 @@ def test_cache_key_accepts_builder_bound_keys(tmp_path):
     (root / "src/repro/core/engine.py").write_text(
         textwrap.dedent("""\
             class Engine:
-                def _cache_key(self, spec, preset, placement, impl):
-                    return (spec, preset, placement.devices, placement.mode, impl)
-
                 def _bucket_key(self, spec, preset, placement, impl, width):
                     base = (spec, preset, placement.devices, placement.mode, impl)
                     return base if width == 1 else base + ("vmap", width)
 
                 def load(self, spec, preset, placement, impl):
-                    key = self._cache_key(spec, preset, placement, impl)
+                    key = self._bucket_key(spec, preset, placement, impl, 1)
 
                     def build():
                         # Closure capture of `key` is a legal binding.
@@ -381,73 +374,18 @@ def test_concurrency_skips_lockfree_and_locked_classes(tmp_path):
     assert run_checks(root, rules=["concurrency"]) == []
 
 
-def test_concurrency_covers_dist_scope(tmp_path):
-    root = write_tree(tmp_path, {"src/repro/dist/sink.py": SINK_UNLOCKED})
+@pytest.mark.parametrize(
+    "path, flagged",
+    [
+        ("src/repro/obs/sink.py", True),
+        # Outside serve/ and obs/: the rule does not look there.
+        ("src/repro/core/sink.py", False),
+    ],
+)
+def test_concurrency_scope(tmp_path, path, flagged):
+    root = write_tree(tmp_path, {path: SINK_UNLOCKED})
     findings = run_checks(root, rules=["concurrency"])
-    assert rule_lines(findings, "concurrency") == [
-        ("src/repro/dist/sink.py", 9)
-    ]
-
-
-# --- dist-proto ----------------------------------------------------------
-
-
-PROTO_UNREGISTERED = """\
-    import dataclasses
-
-    @dataclasses.dataclass(frozen=True)
-    class Hello:
-        proc_id: int
-
-    @dataclasses.dataclass(frozen=True)
-    class Rogue:
-        payload: str
-
-    MESSAGE_TYPES = {"hello": Hello}
-"""
-
-
-def test_dist_proto_fires_on_unregistered_dataclass(tmp_path):
-    root = write_tree(tmp_path, {"src/repro/dist/proto.py": PROTO_UNREGISTERED})
-    findings = run_checks(root, rules=["dist-proto"])
-    assert rule_lines(findings, "dist-proto") == [
-        ("src/repro/dist/proto.py", 8)
-    ]
-    assert "would encode but never decode" in messages(findings)
-    assert main(["--root", str(root), "--rules", "dist-proto"]) == 1
-
-
-def test_dist_proto_fires_on_computed_registry(tmp_path):
-    root = write_tree(
-        tmp_path,
-        {
-            "src/repro/dist/proto.py": """\
-                import dataclasses
-
-                @dataclasses.dataclass(frozen=True)
-                class Hello:
-                    proc_id: int
-
-                MESSAGE_TYPES = dict(hello=Hello)
-            """
-        },
-    )
-    findings = run_checks(root, rules=["dist-proto"])
-    assert "dict literal" in messages(findings)
-
-
-def test_dist_proto_fires_on_non_stdlib_import(tmp_path):
-    root = write_tree(
-        tmp_path,
-        {
-            "src/repro/dist/proto.py": PROTO_UNREGISTERED.replace(
-                "import dataclasses",
-                "import dataclasses\n    import jax",
-            )
-        },
-    )
-    findings = run_checks(root, rules=["dist-proto"])
-    assert "pure-stdlib" in messages(findings)
+    assert rule_lines(findings, "concurrency") == ([(path, 9)] if flagged else [])
 
 
 # --- suppression ---------------------------------------------------------
@@ -556,7 +494,6 @@ def test_live_repo_fingerprint_is_current():
         "stage-discipline",
         "schema-drift",
         "concurrency",
-        "dist-proto",
     ],
 )
 def test_every_rule_is_registered(rule):

@@ -9,7 +9,7 @@ torn final line and turn missing/empty reports into one-line
 import pytest
 
 from repro.core.engine import Engine
-from repro.core.plan import ExecutionPlan
+from repro.core.plan import ExecutionPlan, ServeSpec
 from repro.core.registry import BenchmarkSpec, get_benchmark
 from repro.core.results import (
     BenchmarkRecord,
@@ -19,6 +19,8 @@ from repro.core.results import (
     load_records,
     load_run,
 )
+from repro.serve.lanes import Completion
+from repro.serve.latency import stats_from_completions
 
 FAST = dict(preset=0, iters=1, warmup=0, include_backward=False)
 
@@ -122,3 +124,51 @@ def test_report_error_is_a_value_error():
     # CLI catch sites use `except (PlanError, ValueError)`; ReportError
     # must flow through them.
     assert issubclass(ReportError, ValueError)
+
+
+def _v9_report(tmp_path, serve_extra: dict, record_extra: dict) -> str:
+    """A schema-v9 JSONL report: today's fields plus the v9 columns that
+    left in v10, as a v9 writer produced them."""
+    import dataclasses
+    import json
+
+    serve = ServeSpec(mode="open", qps=100.0, duration_s=1.0)
+    meta = dataclasses.asdict(RunMetadata.capture(preset=0, serve=serve))
+    meta.update(schema_version=9)
+    meta["serve"].update(serve_extra)
+    rec = BenchmarkRecord(
+        name="pathfinder", level=1, dwarf=None, domain=None, preset=0,
+        us_per_call=1.0, achieved_gflops=0.0, achieved_gbps=0.0,
+        compute_util10=0, memory_util10=0, dominant="serve",
+    )
+    comps = [Completion(index=i, lane=0, t_submit=0.01 * i,
+                        t_done=0.01 * i + 0.001, warmup=False)
+             for i in range(8)]
+    rec.apply_serve(stats_from_completions(comps, offered_qps=100.0),
+                    mode="open", lanes=1)
+    row = {**dataclasses.asdict(rec), **record_extra}
+    path = tmp_path / "v9.jsonl"
+    path.write_text(
+        json.dumps({"kind": "meta", **meta}) + "\n"
+        + json.dumps({"kind": "record", **row}) + "\n"
+    )
+    return str(path)
+
+
+@pytest.mark.parametrize("case", ["records", "metadata"])
+def test_v9_report_loads_under_v10(tmp_path, case):
+    """v10 dropped the multi-process load generator's two record columns
+    and its ServeSpec field; a v9 report that carries them still loads."""
+    if case == "records":
+        path = _v9_report(tmp_path, {}, {"client_procs": 2,
+                                         "proc_qps": [48.0, 52.0]})
+    else:
+        path = _v9_report(tmp_path, {"client_procs": 2}, {})
+    meta, (rec,) = load_run(path)
+    assert meta is not None and meta.schema_version == 9
+    if case == "records":
+        csv = rec.csv()
+        assert ";serve=open;" in csv
+        assert "procs" not in csv and "proc_qps" not in csv
+    else:
+        assert meta.serve == ServeSpec(mode="open", qps=100.0, duration_s=1.0)

@@ -11,6 +11,7 @@ import os
 import subprocess
 import sys
 import textwrap
+import time
 
 import pytest
 
@@ -27,6 +28,7 @@ from repro.serve.loadgen import (
     merge_schedules,
     open_loop_lane_schedules,
     open_loop_schedule,
+    save_trace,
 )
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -117,6 +119,27 @@ def test_lane_schedules_deterministic_and_merge_to_target_stream():
         (r for lane in a for r in lane), key=lambda r: r.index
     )
     assert tuple(merged_again) == merged.requests
+
+
+def test_subschedules_deterministic_and_merged_trace_byte_identical(tmp_path):
+    kw = dict(qps=400.0, duration_s=2.0, n_lanes=4, seed=123, warmup=6)
+    subs_a = open_loop_lane_schedules(**kw)
+    subs_b = open_loop_lane_schedules(**kw)
+    assert [s.requests for s in subs_a] == [s.requests for s in subs_b]
+    # Each sub-stream carries its share of the target; the merged stream
+    # is the full offered load in arrival order with dense global indices.
+    merged_a = merge_schedules(subs_a)
+    merged_b = merge_schedules(subs_b)
+    assert merged_a.offered_qps == pytest.approx(400.0)
+    assert [r.index for r in merged_a.requests] == list(range(len(merged_a.requests)))
+    pa, pb = tmp_path / "a.jsonl", tmp_path / "b.jsonl"
+    save_trace(merged_a, str(pa))
+    save_trace(merged_b, str(pb))
+    assert pa.read_bytes() == pb.read_bytes()
+    # A different seed is a different stream — the traces must not collide.
+    other = merge_schedules(open_loop_lane_schedules(**{**kw, "seed": 124}))
+    save_trace(other, str(pb))
+    assert pa.read_bytes() != pb.read_bytes()
 
 
 def test_closed_loop_schedule_marks_warmup_prefix():
@@ -266,6 +289,55 @@ def test_lane_qps_zero_fills_starved_lanes():
     assert stats.lane_qps[2] > 0
 
 
+def _synthetic_completions(n_streams: int, lanes: int, per_lane: int):
+    """Per-stream completion lists with distinct latencies everywhere."""
+    streams = []
+    k = 0
+    for _ in range(n_streams):
+        rows = []
+        for lane in range(lanes):
+            for i in range(per_lane):
+                t = 0.01 * k
+                rows.append(Completion(
+                    index=k, lane=lane, t_submit=t, t_done=t + 0.001 * (k % 17 + 1),
+                    warmup=k < 3,
+                ))
+                k += 1
+        streams.append(rows)
+    return streams
+
+
+def test_percentiles_invariant_to_completion_order_and_lane_relabelling():
+    lanes = 2
+    streams = _synthetic_completions(n_streams=3, lanes=lanes, per_lane=40)
+    # Relabel to global lanes and order by t_done: the same completions,
+    # so the same percentiles as the plain concatenation.
+    merged = sorted(
+        (
+            dataclasses.replace(c, lane=k * lanes + c.lane)
+            for k, rows in enumerate(streams)
+            for c in rows
+        ),
+        key=lambda c: c.t_done,
+    )
+    concat = [c for rows in streams for c in rows]
+    a = stats_from_completions(merged, offered_qps=300.0, n_lanes=3 * lanes)
+    b = stats_from_completions(concat, offered_qps=300.0)
+    assert (a.p50_us, a.p95_us, a.p99_us) == (b.p50_us, b.p95_us, b.p99_us)
+    assert a.requests == b.requests
+    assert a.achieved_qps == pytest.approx(b.achieved_qps)
+    assert a.lane_qps is not None and len(a.lane_qps) == 3 * lanes
+
+
+def test_too_short_duration_yields_explicit_empty_schedule():
+    sched = open_loop_schedule(qps=0.5, duration_s=1e-9, seed=0)
+    assert len(sched) == 0
+    assert sched.truncated is False
+    assert sched.offered_qps == 0.5
+    with pytest.raises(ValueError, match="schedule was empty"):
+        stats_from_completions(list(sched), offered_qps=0.5)
+
+
 # -- ServeSpec / plan ------------------------------------------------------
 
 
@@ -367,6 +439,39 @@ def test_threaded_worker_error_propagates():
         run_closed_loop_threaded(
             call, concurrency=2, n_lanes=2, duration_s=0.5
         )
+
+
+def _slow_call():
+    time.sleep(0.05)
+
+
+@pytest.mark.parametrize("loop", ["single", "threaded", "colocated"])
+def test_closed_loops_issue_past_the_window_until_a_request_is_measured(loop):
+    """Calls slower than the window: two 50 ms calls fill a 0.1 s window,
+    short of a 4-request warmup. Each closed loop issues on past the
+    window until every lane or tenant has exactly one measured request,
+    instead of leaving only warmup completions to measure."""
+    from repro.serve.interference import measure_colocation
+    from repro.serve.lanes import run_closed_loop
+
+    kw = dict(concurrency=2, duration_s=0.1, warmup=4)
+    if loop == "single":
+        comps = run_closed_loop(_slow_call, n_lanes=1, **kw)
+        assert [c.warmup for c in comps] == [True] * 4 + [False]
+    elif loop == "threaded":
+        comps = run_closed_loop_threaded(_slow_call, n_lanes=2, **kw).completions
+        for lane in (0, 1):  # ceil(4 / 2) = 2 warmup requests a lane
+            assert sorted(c.warmup for c in comps if c.lane == lane) == [
+                False, True, True,
+            ]
+    else:
+        result = measure_colocation(
+            {"a": _slow_call, "b": _slow_call},
+            concurrency=4, n_lanes=2, duration_s=0.1, warmup=4,
+        )
+        for name in ("a", "b"):
+            assert result.isolated[name].requests == 1
+            assert result.colocated[name].requests == 1
 
 
 # -- engine serve stage ----------------------------------------------------

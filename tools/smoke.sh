@@ -39,18 +39,6 @@
 # within 10% of the run's wall time, that the final metadata line holds
 # the counter snapshot, and that tools/trace_report.py reads the file.
 #
-# With --dist, instead run the distributed load-generation smoke on a
-# forced-8-host-device topology: 2 client processes replay seeded
-# sub-schedules against a shared --cache-dir (cold run stores, warm run
-# must restore the executable in *every* client — the summed
-# `# dist-cache` counters must show zero misses and zero XLA compiles),
-# with merged percentiles, per-process QPS summing to the merged
-# throughput, and a deterministic request count across runs. On hosts
-# with >=2 cores it additionally asserts 2 client processes sustain
-# >= 1.5x the single-process threaded client's achieved QPS at the same
-# saturating offered load (on a single core the processes serialize at
-# the hardware, so the scaling assertion is skipped with a note).
-#
 # With --check, instead run the static lint leg: the repro.check contract
 # checker (AST-only, needs no JAX) must exit clean, and ruff (F/E9/B
 # scope, see ruff.toml) runs when installed. This is the only leg that
@@ -438,102 +426,6 @@ print(f"trace smoke: {len(spans)} spans over stages "
 PY
 
   python tools/trace_report.py "$out/run.trace.json"
-  exit 0
-fi
-
-if [[ "${1:-}" == "--dist" ]]; then
-  export XLA_FLAGS="--xla_force_host_platform_device_count=8${XLA_FLAGS:+ $XLA_FLAGS}"
-  cache="$out/cache"
-  common=(--names pathfinder --preset 0 --iters 1 --warmup 0 --no-backward
-    --serve open --serve-duration 1 --concurrency 16 --lanes 4)
-
-  # Cold distributed run: 2 client processes derive their sub-schedules
-  # from the shared seed, compile through the shared cache, and stream
-  # completion stamps back for merged accounting.
-  python -m repro.core.suite "${common[@]}" --qps 4000 --client-procs 2 \
-    --cache-dir "$cache" --jsonl "$out/dist_cold.jsonl" 2> "$out/dist_cold.err" \
-    || { cat "$out/dist_cold.err" >&2; exit 1; }
-  grep '^# dist-cache' "$out/dist_cold.err"
-  # Warm: same spec; every client process must restore its executable.
-  python -m repro.core.suite "${common[@]}" --qps 4000 --client-procs 2 \
-    --cache-dir "$cache" --jsonl "$out/dist_warm.jsonl" 2> "$out/dist_warm.err" \
-    || { cat "$out/dist_warm.err" >&2; exit 1; }
-  grep '^# dist-cache' "$out/dist_warm.err"
-
-  python - "$out/dist_cold.jsonl" "$out/dist_warm.jsonl" "$out/dist_warm.err" <<'PY'
-import re
-import sys
-
-from repro.core.results import load_run
-
-cold_meta, cold_records = load_run(sys.argv[1])
-warm_meta, warm_records = load_run(sys.argv[2])
-with open(sys.argv[3]) as f:
-    (line,) = [l for l in f if l.startswith("# dist-cache")]
-counters = {k: int(v) for k, v in re.findall(r"(\w+)=(\d+)", line)}
-
-for meta in (cold_meta, warm_meta):
-    assert meta is not None and meta.schema_version >= 9, meta
-    assert meta.serve is not None and meta.serve.client_procs == 2, meta.serve
-for tag, records in (("cold", cold_records), ("warm", warm_records)):
-    (rec,) = records
-    assert rec.status == "ok", (tag, rec.error)
-    assert rec.client_procs == 2, rec.client_procs
-    assert rec.proc_qps and len(rec.proc_qps) == 2, rec.proc_qps
-    assert rec.latency_p50_us and rec.latency_p99_us and rec.achieved_qps, rec
-    # Per-process accounting must sum back to the merged throughput.
-    assert abs(sum(rec.proc_qps) - rec.achieved_qps) < 0.1 * rec.achieved_qps, (
-        rec.proc_qps, rec.achieved_qps)
-(cold_rec,) = cold_records
-(warm_rec,) = warm_records
-# Same seed -> same SeedSequence split -> same merged request count.
-assert cold_rec.serve_requests == warm_rec.serve_requests, (
-    cold_rec.serve_requests, warm_rec.serve_requests)
-# The zero-compile warm distributed run: the summed client counters show
-# every process restored its executable from the shared cache.
-assert counters["misses"] == 0, line
-assert counters["fallback_count"] == 0, line
-assert counters["hits"] == 2, line
-print(f"dist smoke: 2 client procs, {warm_rec.serve_requests} merged "
-      f"requests, proc_qps={[round(q) for q in warm_rec.proc_qps]}, "
-      "warm run 0 XLA compiles in every client")
-PY
-
-  # Scaling: 2 client processes must clear the single-interpreter
-  # dispatch ceiling. Only meaningful with >=2 cores — a single-core
-  # host serializes the processes at the hardware level, so there the
-  # leg stops at the accounting + zero-compile assertions above.
-  if [[ "$(python -c 'import os; print(os.cpu_count() or 1)')" -ge 2 ]]; then
-    for attempt in 1 2; do
-      python -m repro.core.suite "${common[@]}" --qps 25000 \
-        --serve-client threaded --cache-dir "$cache" \
-        --jsonl "$out/ceil_single.jsonl"
-      python -m repro.core.suite "${common[@]}" --qps 25000 --client-procs 2 \
-        --cache-dir "$cache" --jsonl "$out/ceil_dist.jsonl"
-      if python - "$out/ceil_single.jsonl" "$out/ceil_dist.jsonl" <<'PY'
-import sys
-
-from repro.core.results import load_run
-
-_, (single,) = load_run(sys.argv[1])
-_, (dist,) = load_run(sys.argv[2])
-assert single.status == "ok", single.error
-assert dist.status == "ok", dist.error
-ratio = dist.achieved_qps / single.achieved_qps
-print(f"dist scaling: 2 procs {dist.achieved_qps:.0f} qps vs single "
-      f"{single.achieved_qps:.0f} qps ({ratio:.2f}x)")
-assert ratio >= 1.5, f"2-process scaling only {ratio:.2f}x (< 1.5x)"
-PY
-      then
-        exit 0
-      fi
-      echo "dist scaling attempt $attempt below 1.5x; retrying" >&2
-    done
-    echo "dist smoke: 2 procs failed to reach 1.5x single-process QPS" >&2
-    exit 1
-  else
-    echo "# dist smoke: single-core host, scaling assertion skipped" >&2
-  fi
   exit 0
 fi
 
