@@ -18,12 +18,18 @@ one chip of an expert-parallel deployment runs it: the router scores all
 ``n_experts``, the layer computes only the experts it holds
 (``held_experts``), with every token routed to them and none dropped, plus
 the shared experts every token passes through. Assignments are sorted by
-held expert and the experts run as one grouped matmul
-(``jax.lax.ragged_dot``). What the experts held elsewhere add is left out.
-Its weights are the checkpoint's own tensors (``mlp.gate.weight``, ...).
+held expert, the held ones first, into a row buffer sized by the layer's
+held load: the smallest rung of a ladder fixed by the shapes
+(``row_ladder``) that holds them all, N·k rows at worst. The experts run on
+it as one grouped matmul (``jax.lax.ragged_dot``), and each row, weighted
+by its routing weight, is added into its token's output. What the experts
+held elsewhere add is left out. Its weights are the checkpoint's own
+tensors (``mlp.gate.weight``, ...).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +38,16 @@ from repro.kernels import ops
 from repro.models.config import ArchConfig
 from repro.models.layers import dtype_of, init_dense
 
-__all__ = ["init_moe", "apply_moe", "moe_oracle", "route_sigmoid", "apply_moe_dropless", "swiglu"]
+__all__ = [
+    "init_moe",
+    "apply_moe",
+    "moe_oracle",
+    "route_sigmoid",
+    "row_ladder",
+    "dropless_rows",
+    "apply_moe_dropless",
+    "swiglu",
+]
 
 ROUTER = "mlp.gate.weight"
 BIAS = "mlp.gate.e_score_correction_bias"
@@ -164,6 +179,53 @@ def swiglu(x, w_gate, w_up, w_down):
     return ops.matmul(jax.nn.silu(ops.matmul(x, w_gate)) * ops.matmul(x, w_up), w_down)
 
 
+def row_ladder(n_tokens: int, cfg: ArchConfig) -> tuple[int, ...]:
+    """The row buffers the dropless layer may run on, ascending, from the
+    shapes alone: N·k / 2^j rows rounded up to 128, for j = 0 ... J with
+    J = floor(log2(n_experts / held)) - 1, so that the smallest holds twice
+    the held experts' uniform share of the N·k assignments. N·k, every
+    assignment held, is always the last."""
+    first, stop = cfg.held
+    nk = n_tokens * cfg.top_k
+    J = max(0, (cfg.n_experts // (stop - first)).bit_length() - 2)
+    rungs = {-(-nk // (128 << j)) * 128 for j in range(J + 1)}
+    return tuple(sorted(r for r in rungs if r < nk)) + (nk,)
+
+
+def dropless_rows(n_tokens: int, cfg: ArchConfig, total):
+    """Rows of the buffer the dropless layer runs on when ``total`` of its
+    N·k assignments go to held experts: the smallest rung of ``row_ladder``
+    that holds them (``total`` may be traced)."""
+    ladder = jnp.asarray(row_ladder(n_tokens, cfg))
+    return ladder[jnp.sum(ladder[:-1] < total)]
+
+
+def _held_rows(rows: int, p: dict, cfg: ArchConfig, x, order, counts, w):
+    """The held experts on the first ``rows`` sorted assignments, and each
+    row times its routing weight added into its token: (N, d) in f32."""
+    N, d = x.shape
+    with jax.named_scope(f"rows{rows}"):
+        head = order[:rows]
+        xs = x[head // cfg.top_k]
+        gate = jax.lax.ragged_dot(xs, p[EXPERTS.format("gate")], counts)
+        up = jax.lax.ragged_dot(xs, p[EXPERTS.format("up")], counts)
+        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p[EXPERTS.format("down")], counts)
+        with jax.named_scope("combine"):
+            # The rows in assignment order, so in token order: the adds then
+            # take the rows as they come, with no sorted copy of their own.
+            perm = jnp.argsort(head)
+            slot = head[perm]
+            # Rows past the held groups are no expert's output: select, not
+            # multiply, so whatever they hold cannot leak in.
+            held = (perm < jnp.sum(counts))[:, None]
+            part = jnp.where(held, ys[perm].astype(jnp.float32) * w.reshape(-1)[slot][:, None], 0)
+            return (
+                jnp.zeros((N, d), jnp.float32)
+                .at[slot // cfg.top_k]
+                .add(part, indices_are_sorted=True)
+            )
+
+
 def apply_moe_dropless(p: dict, cfg: ArchConfig, x: jax.Array):
     """x (N, d) -> (y (N, d), tokens routed to each held expert (held,)).
 
@@ -171,8 +233,7 @@ def apply_moe_dropless(p: dict, cfg: ArchConfig, x: jax.Array):
     bias (E,) in f32, the held experts' SwiGLUs stacked (held, d, f) /
     (held, f, d), and the shared experts as one SwiGLU of width
     n_shared_experts·f."""
-    N, d = x.shape
-    k = cfg.top_k
+    N = x.shape[0]
     first, stop = cfg.held
     held = stop - first
     with jax.named_scope("route"):
@@ -188,16 +249,10 @@ def apply_moe_dropless(p: dict, cfg: ArchConfig, x: jax.Array):
         order = jnp.argsort(group, stable=True)
         counts = jnp.sum(group[:, None] == jnp.arange(held), axis=0, dtype=jnp.int32)
     with jax.named_scope("experts"):
-        xs = x[order // k]
-        gate = jax.lax.ragged_dot(xs, p[EXPERTS.format("gate")], counts)
-        up = jax.lax.ragged_dot(xs, p[EXPERTS.format("up")], counts)
-        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p[EXPERTS.format("down")], counts)
-    with jax.named_scope("combine"):
-        back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k, dtype=order.dtype))
-        # Rows past the held groups are no expert's output: select, not
-        # multiply, so whatever they hold cannot leak in.
-        per_slot = jnp.where(mine[..., None], ys[back].reshape(N, k, d), 0)
-        y = jnp.sum(per_slot.astype(jnp.float32) * w[..., None], axis=1)
+        ladder = row_ladder(N, cfg)
+        rung = jnp.argmax(jnp.asarray(ladder) == dropless_rows(N, cfg, jnp.sum(counts)))
+        branches = [functools.partial(_held_rows, rows, p, cfg) for rows in ladder]
+        y = jax.lax.switch(rung, branches, x, order, counts, w)
     with jax.named_scope("shared"):
         shared = swiglu(x, *(p[SHARED.format(n)] for n in ("gate", "up", "down")))
     return (y + shared.astype(jnp.float32)).astype(x.dtype), counts

@@ -1,7 +1,8 @@
 """Moonlight-16B-A3B (a DeepSeek-V3 stack) against its plain float32
 reference, at the smoke config's widths, all in float32: the forward on
 both matmul implementations, prefill then decode through the latent cache,
-the expert share, dropless routing and the router's bias."""
+the expert share, dropless routing, the held-expert row buffer and the
+router's bias."""
 
 import dataclasses
 
@@ -11,11 +12,21 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.bench.dnn.lm_prefill import arch_config
 from repro.configs import get_config, get_smoke_config
 from repro.kernels import ops
 from repro.models import Model, deepseek_ref, mla
 from repro.models.model import STACK, ckpt_layout, dense_prefix
-from repro.models.moe import BIAS, ROUTER, SHARED, apply_moe_dropless, route_sigmoid, swiglu
+from repro.models.moe import (
+    BIAS,
+    ROUTER,
+    SHARED,
+    apply_moe_dropless,
+    dropless_rows,
+    route_sigmoid,
+    row_ladder,
+    swiglu,
+)
 
 
 def _cfg(held_experts=(0, 4)):
@@ -149,6 +160,73 @@ def test_routing_every_token_to_one_expert_drops_none():
     np.testing.assert_array_equal(counts, [256, 256, 256, 0])
     np.testing.assert_array_equal(counts, want_counts)
     np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+
+
+# 4 of 32 experts held, top-4: 256 tokens give 1,024 assignments, and the
+# row ladder (256, 512, 1024).
+N_LADDER = 256
+
+
+def _ladder_cfg():
+    return dataclasses.replace(_cfg(), n_experts=32, top_k=4)
+
+
+@pytest.mark.parametrize(
+    "held_bias, rows",
+    [
+        (None, 256),  # the drawn router: about 4/32 of the slots held
+        ((10.0, 10.0, -10.0, -10.0), 512),  # two slots of every token held
+        ((10.0, 10.0, 10.0, 10.0), 1024),  # every slot held: the N·k rung
+    ],
+    ids=["uniform", "half", "all"],
+)
+def test_the_row_buffer_follows_the_held_load_and_drops_none(held_bias, rows):
+    cfg = _ladder_cfg()
+    p = _moe_layer(_weights(cfg))
+    if held_bias is not None:
+        p[BIAS] = p[BIAS].at[:4].set(jnp.array(held_bias))
+    x = jax.random.normal(jax.random.key(6), (N_LADDER, p[ROUTER].shape[0]))
+    y, counts = jax.jit(lambda p, x: apply_moe_dropless(p, cfg, x))(p, x)
+    want, want_counts = deepseek_ref.moe_layer(p, cfg, x)
+    assert row_ladder(N_LADDER, cfg) == (256, 512, 1024)
+    assert int(dropless_rows(N_LADDER, cfg, int(np.sum(counts)))) == rows
+    np.testing.assert_array_equal(counts, want_counts)
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+
+
+def test_the_row_ladder_comes_from_the_shapes_and_picks_the_smallest_rung_that_holds():
+    # The cell: preset 3, 8 x 4,096 tokens, top-6, 8 of 64 experts held.
+    cell = arch_config("moonlight-16b-a3b", "published", 8)
+    assert row_ladder(8 * 4096, cell) == (49152, 98304, 196608)
+    # The smoke config (4 of 8 held) and a decode step keep N·k rows alone.
+    smoke = arch_config("moonlight-16b-a3b", "smoke", 4)
+    assert row_ladder(2 * 32, smoke) == (2 * 32 * 3,)
+    assert row_ladder(8, cell) == (48,)
+    rng = np.random.default_rng(0)
+    for n, cfg in ((8 * 4096, cell), (N_LADDER, _ladder_cfg()), (64, smoke)):
+        ladder = row_ladder(n, cfg)
+        nk = n * cfg.top_k
+        totals = [0, nk, *ladder, *(r + 1 for r in ladder[:-1]), *rng.integers(0, nk + 1, 64)]
+        for total in totals:
+            rows = int(dropless_rows(n, cfg, total))
+            assert rows in ladder and rows >= total  # dropless
+            assert rows == min(r for r in ladder if r >= total)
+
+
+def test_each_rung_runs_under_its_own_scope():
+    """The device trace attributes the experts' time to ``rows{R}``, one
+    scope per rung. Lowered at the cell's shapes, nothing compiled."""
+    cfg = arch_config("moonlight-16b-a3b", "published", 8)
+    p = {
+        k[len(STACK):]: jax.ShapeDtypeStruct(shape[1:], dt)
+        for k, (shape, dt, _) in ckpt_layout(cfg).items()
+        if k.startswith(STACK)
+    }
+    x = jax.ShapeDtypeStruct((8 * 4096, cfg.d_model), jnp.bfloat16)
+    text = jax.jit(lambda p, x: apply_moe_dropless(p, cfg, x)).lower(p, x).as_text(debug_info=True)
+    assert "/experts/cond/branch_" in text
+    for rows in row_ladder(8 * 4096, cfg):
+        assert f"/rows{rows}/ragged_dot_general" in text
 
 
 def test_the_router_bias_selects_but_does_not_weight():
